@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row, time_fn
-from repro.kernels.compat import default_interpret
+from repro.kernels.compat import resolve_interpret
 from repro.kernels.paged_attn.ops import paged_attention
 
 
@@ -44,7 +44,7 @@ def paged_decode_attention(smoke: bool = False):
     rows = []
     ctxs = (256, 1024) if smoke else (512, 2048, 8192)
     iters = 5 if smoke else 20
-    interp = default_interpret()
+    interp = resolve_interpret(None)
     rng = np.random.default_rng(0)
     for ctx in ctxs:
         q, kp, vp, tbl, pos = _case(rng, ctx)

@@ -304,6 +304,9 @@ def main(argv=None) -> None:
         compare(*args.compare)
         return
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.autotune:
         from repro.kernels import autotune
         for kernel, bucket, geom, backend in autotune.tune_standard(

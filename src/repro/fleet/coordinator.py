@@ -34,7 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
-from repro.launch.mesh import make_submesh, partition_devices
+from repro.launch.mesh import make_mesh, make_submesh, partition_devices
 
 
 @dataclass(frozen=True)
@@ -141,7 +141,7 @@ class DistributedCoordinator(Coordinator):
         # mesh; the per-host identity is the process index.
         n = len(jax.devices())
         mp = model_parallel if n % model_parallel == 0 else 1
-        mesh = jax.make_mesh((n // mp, mp), ("data", "model"))
+        mesh = make_mesh((n // mp, mp), ("data", "model"))
         self._host = FleetHost(self._index, tuple(jax.local_devices()), mesh)
 
     def hosts(self) -> List[FleetHost]:

@@ -14,15 +14,17 @@ hosts must not drag the fleet median toward zero), and
 :meth:`FleetServer.slos` reads the SLO trio off the MERGED registry view, so
 fleet TTFT/TPOT percentiles are exact as-if-one-registry numbers.
 
-Params are fanned out as host (uncommitted) arrays once at construction:
-committed arrays from one sub-mesh cannot feed another sub-mesh's
-computation, and uncommitted leaves place freely on every host.
+Params are copied once at construction onto each host's own devices,
+replicated over its sub-mesh, so every host's steps run there: committed
+arrays pin a computation to their devices, and uncommitted ones would leave
+every host's steps on the default device.
 """
 from __future__ import annotations
 
 from typing import Dict, List
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.fleet.fleet_engine import FleetEngine
 from repro.launch.server import Handle, Request, Server
@@ -38,10 +40,11 @@ class FleetServer:
     def __init__(self, cfg, params, fleet: FleetEngine, **server_kw):
         self.fleet = fleet
         self.attn_impl = None
-        host_params = jax.tree.map(lambda x: jax.device_get(x), params)
         self.servers: Dict[int, Server] = {}
         for h in fleet.active_hosts():
             eng = fleet.engine(h)
+            host_params = jax.device_put(
+                params, NamedSharding(eng.mesh, PartitionSpec()))
             with eng.activate():
                 srv = Server(cfg, host_params, engine=eng, host=h,
                              **server_kw)

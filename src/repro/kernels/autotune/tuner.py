@@ -293,9 +293,9 @@ def tune(kernel: str, shapes: Dict[str, int],
     with ZERO trials — delete the cache entry (or point
     ``REPRO_AUTOTUNE_CACHE`` at a fresh file) to force a re-tune.
     """
-    from repro.kernels.compat import kernel_caps
+    from repro.kernels.compat import resolve_interpret
 
-    it = kernel_caps(interpret).interpret
+    it = resolve_interpret(interpret)
     c = cache if cache is not None else get_cache()
     reg = registry if registry is not None else get_registry()
     bucket = shape_bucket(shapes)
@@ -349,9 +349,9 @@ def tune_standard(smoke: bool = True, registry=None) -> List[Tuple[str, str,
     ``paged_attn``.  Returns (kernel, bucket, geometry, backend) rows for
     the CSV.
     """
-    from repro.kernels.compat import kernel_caps
+    from repro.kernels.compat import resolve_interpret
 
-    backend = backend_key(kernel_caps(None).interpret)
+    backend = backend_key(resolve_interpret(None))
     rows = []
     bitplane_shapes = [{"m": 64, "k": 512, "n": 64, "ba": 8, "bw": 8}]
     paged_shapes = [{"b": 4, "kv": 2, "rep": 2, "hd": 64, "bs": 16, "mb": 8}]

@@ -23,9 +23,11 @@ Implementation notes (TPU adaptation):
     activation planes tensor [PA, M, K] and the weight planes tensor
     [PW, K, N] are streamed block-by-block — VMEM never holds more than one
     (bm, bk) + (bk, bn) plane slice.
-  * group MACs are a G-batched (bm, rows) x (rows, bn) dot_general as in
-    rbl_decode; V(k) is the fitted two-regime physics on the VPU; the
-    comparator bank is ``rows`` broadcast compares.
+  * group MACs are one (bm, rows) x (rows, bn) bf16 dot per row-group,
+    unrolled over the ``bk // rows`` groups of a K-block and stacked into
+    (groups, bm, bn) counts (Mosaic lowers no in-kernel reshape that splits
+    the lane axis into groups); V(k) is the fitted two-regime physics on
+    the VPU; the comparator bank is ``rows`` broadcast compares.
   * the plane weight 2^(p+q) is computed from ``pl.program_id`` on the fly
     (shift of an int32 one), and accumulation is int32 — float32 would lose
     bit-exactness beyond 2^24 for deep-K 8-bit operands.
@@ -57,12 +59,24 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import constants as C
 from repro.kernels.common import (decode_counts, decode_counts_noisy,
                                   make_normal_sampler)
-from repro.kernels.compat import compiler_params
 
 
-def _make_kernel(rows: int, bk: int, bits_w: int):
-    groups = bk // rows
+def _group_counts(a_ref, b_ref, rows: int):
+    """counts[g, m, n] = sum_r a[m, g*rows + r] * b[g*rows + r, n]: the
+    binary MAC of every row-group of the block, exact in f32 (at most
+    ``rows``).  One (bm, rows) x (rows, bn) bf16 dot per group (bits are
+    {0,1}, so bf16 is exact); the weight block goes through f32 so that its
+    row-group slices fall on whole (8, 128) tiles."""
+    a = a_ref[0].astype(jnp.float32).astype(jnp.bfloat16)
+    b = b_ref[0].astype(jnp.float32)
+    return jnp.stack([
+        jax.lax.dot_general(
+            a[:, lo:lo + rows], b[lo:lo + rows, :].astype(jnp.bfloat16),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        for lo in range(0, a.shape[1], rows)])
 
+
+def _make_kernel(rows: int, bits_w: int):
     def kernel(a_ref, b_ref, thr_ref, o_ref, acc_ref):
         pp = pl.program_id(2)
         kk = pl.program_id(3)
@@ -71,15 +85,8 @@ def _make_kernel(rows: int, bk: int, bits_w: int):
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        bm = a_ref.shape[1]
-        bn = b_ref.shape[2]
-        a = a_ref[0].astype(jnp.float32).reshape(bm, groups, rows)
-        b = b_ref[0].astype(jnp.float32).reshape(groups, rows, bn)
-        # counts[g, m, n] = sum_r a[m, g, r] * b[g, r, n]
-        counts = jax.lax.dot_general(
-            a, b, (((2,), (1,)), ((1,), (0,))),
-            preferred_element_type=jnp.float32)
-        dec = decode_counts(counts, thr_ref[...], rows)
+        dec = decode_counts(_group_counts(a_ref, b_ref, rows), thr_ref[...],
+                            rows)
         # digital shift-accumulate: weight = 2^(p+q), pair index pp = p*PW + q
         shift = pp // bits_w + pp % bits_w
         weight = jax.lax.shift_left(jnp.int32(1), shift)
@@ -111,7 +118,7 @@ def bitplane_mac_raw(a_planes, w_planes, thresholds, *, rows: int = C.ROWS,
     assert bk % rows == 0
     grid = (m // bm, n // bn, pa * pw, k // bk)
     return pl.pallas_call(
-        _make_kernel(rows, bk, pw),
+        _make_kernel(rows, pw),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda i, j, pp, kk: (pp // pw, i, kk)),
@@ -121,7 +128,7 @@ def bitplane_mac_raw(a_planes, w_planes, thresholds, *, rows: int = C.ROWS,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, pp, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -151,15 +158,8 @@ def _make_noisy_kernel(rows: int, bk: int, bits_w: int, mismatch_sigma,
         normal = make_normal_sampler(
             (seed_ref[0], seed_ref[1], step), hw_prng=hw_prng)
 
-        bm = a_ref.shape[1]
-        bn = b_ref.shape[2]
-        a = a_ref[0].astype(jnp.float32).reshape(bm, groups, rows)
-        b = b_ref[0].astype(jnp.float32).reshape(groups, rows, bn)
-        counts = jax.lax.dot_general(
-            a, b, (((2,), (1,)), ((1,), (0,))),
-            preferred_element_type=jnp.float32)
         dec = decode_counts_noisy(
-            counts, thr_ref[...], rows, normal,
+            _group_counts(a_ref, b_ref, rows), thr_ref[...], rows, normal,
             mismatch_sigma=mismatch_sigma,
             comparator_offset_sigma=comparator_sigma)
         # Padded K-groups (beyond the operand's real K) must not decode:
@@ -227,7 +227,7 @@ def bitplane_mac_noisy_raw(a_planes, w_planes, thresholds, seed, *,
                            valid_groups=valid_groups),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,

@@ -18,7 +18,6 @@ Explicit ``bm``/``bn``/``bk`` arguments always win over the tuner.
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +28,7 @@ from repro.core.quant import to_bitplanes
 from repro.kernels import autotune
 from repro.kernels.bitplane_mac.bitplane_mac import (bitplane_mac_noisy_raw,
                                                      bitplane_mac_raw)
-from repro.kernels.compat import kernel_caps
-from repro.telemetry import get_registry
+from repro.kernels.compat import resolve_interpret
 
 
 def _resolve_geometry(m: int, k: int, n: int, bits_a: int, bits_w: int,
@@ -83,18 +81,18 @@ def bitplane_mac(u_a, u_w, thr=None, *, bits_a: int = 8, bits_w: int = 8,
     shape bucket; pass explicit values to override.
     Returns int32[..., N] == u_a @ u_w (noise-free decode is exact).
     """
-    caps = kernel_caps(interpret)
+    interpret = resolve_interpret(interpret)
     batch = u_a.shape[:-1]
     m = 1
     for b in batch:
         m *= b
     geom = _resolve_geometry(m, u_a.shape[-1], u_w.shape[-1], bits_a, bits_w,
-                             bm, bn, bk, caps.interpret)
+                             bm, bn, bk, interpret)
     if thr is None:
         thr = core_thresholds(rows, mode="physics")
     return _bitplane_mac_jit(u_a, u_w, thr, bits_a=bits_a, bits_w=bits_w,
                              rows=rows, bm=geom["bm"], bn=geom["bn"],
-                             bk=geom["bk"], interpret=caps.interpret)
+                             bk=geom["bk"], interpret=interpret)
 
 
 def _key_words(key):
@@ -146,36 +144,6 @@ def _bitplane_mac_noisy_jit(u_a, u_w, thr, key, *, bits_a, bits_w, rows,
     return out[:m, :n].reshape(*batch, n)
 
 
-_WARNED_PRNG_FALLBACK = False
-
-
-def _prng_fallback(u_a, u_w, key, *, bits_a, bits_w, rows,
-                   mismatch_sigma, comparator_offset_sigma):
-    """jnp keyed engine fallback when no in-kernel PRNG exists.
-
-    Only reachable on a compiled-TPU jax too old for the Mosaic PRNG
-    primitives (interpret mode always has the counter-hash fallback).  Warns
-    ONCE per process — an engine switch is a statistics change the user
-    should see — and counts every occurrence in telemetry.
-    """
-    global _WARNED_PRNG_FALLBACK
-    if not _WARNED_PRNG_FALLBACK:
-        warnings.warn(
-            "bitplane_mac_noisy: no in-kernel PRNG on this jax build "
-            "(pltpu.prng_seed/prng_random_bits missing); falling back to "
-            "the plane-batched jnp noise engine. Results stay statistically "
-            "correct but use a different PRNG stream.",
-            RuntimeWarning, stacklevel=3)
-        _WARNED_PRNG_FALLBACK = True
-    get_registry().counter("bitplane_mac.noisy_jnp_fallback").inc()
-    from repro.core.bitserial import bitserial_matmul_unsigned
-
-    return bitserial_matmul_unsigned(
-        u_a, u_w, bits_a=bits_a, bits_w=bits_w, rows=rows, mode="sim",
-        key=key, mismatch_sigma=mismatch_sigma,
-        comparator_offset_sigma=comparator_offset_sigma, rbl_mode="physics")
-
-
 def bitplane_mac_noisy(u_a, u_w, key, thr=None, *, bits_a: int = 8,
                        bits_w: int = 8, rows: int = C.ROWS,
                        mismatch_sigma: float | None = None,
@@ -192,22 +160,17 @@ def bitplane_mac_noisy(u_a, u_w, key, thr=None, *, bits_a: int = 8,
     construction, so cross-engine agreement is statistical (moments /
     quantiles), never bitwise — tests pin it that way.
     """
-    caps = kernel_caps(interpret)
+    interpret = resolve_interpret(interpret)
     if thr is None:
         thr = core_thresholds(rows, mode="physics")
-    if not caps.prng:
-        return _prng_fallback(
-            u_a, u_w, key, bits_a=bits_a, bits_w=bits_w, rows=rows,
-            mismatch_sigma=mismatch_sigma,
-            comparator_offset_sigma=comparator_offset_sigma)
     batch = u_a.shape[:-1]
     m = 1
     for b in batch:
         m *= b
     geom = _resolve_geometry(m, u_a.shape[-1], u_w.shape[-1], bits_a, bits_w,
-                             bm, bn, bk, caps.interpret)
+                             bm, bn, bk, interpret)
     return _bitplane_mac_noisy_jit(
         u_a, u_w, thr, key, bits_a=bits_a, bits_w=bits_w, rows=rows,
         mismatch_sigma=mismatch_sigma,
         comparator_offset_sigma=comparator_offset_sigma, bm=geom["bm"],
-        bn=geom["bn"], bk=geom["bk"], interpret=caps.interpret)
+        bn=geom["bn"], bk=geom["bk"], interpret=interpret)

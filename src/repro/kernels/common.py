@@ -93,10 +93,29 @@ def _mix32(x):
     return x
 
 
+def _mix32_i32(x):
+    """:func:`_mix32` on int32 scalars (the same bits; Mosaic keeps scalar
+    arithmetic in int32)."""
+    def c(v):
+        return jnp.int32(v - (1 << 32) if v >= 1 << 31 else v)
+
+    x = x ^ jax.lax.shift_right_logical(x, c(16))
+    x = x * c(0x85EBCA6B)
+    x = x ^ jax.lax.shift_right_logical(x, c(13))
+    x = x * c(0xC2B2AE35)
+    x = x ^ jax.lax.shift_right_logical(x, c(16))
+    return x
+
+
 def _bits_to_uniform(bits):
-    """uint32/int32 random bits -> f32 uniform in [0, 1) (24-bit mantissa)."""
-    top = jax.lax.shift_right_logical(bits.astype(jnp.uint32),
-                                      jnp.full(bits.shape, 8, jnp.uint32))
+    """uint32/int32 random bits -> f32 uniform in [0, 1) (24-bit mantissa).
+
+    Converts through int32 (the shifted value is below 2^24): Mosaic has no
+    uint32 -> float32 cast.
+    """
+    if bits.dtype != jnp.int32:
+        bits = jax.lax.bitcast_convert_type(bits, jnp.int32)
+    top = jax.lax.shift_right_logical(bits, jnp.full(bits.shape, 8, jnp.int32))
     return top.astype(jnp.float32) * _INV_2_24
 
 
@@ -124,7 +143,11 @@ def make_normal_sampler(seeds, *, hw_prng: bool):
     """
     counter = [0]
     if hw_prng:
-        pltpu.prng_seed(*seeds)
+        # The hardware PRNG takes at most two seed words: fold the rest in.
+        w0, w1 = (jnp.asarray(s, jnp.int32) for s in seeds[:2])
+        for s in seeds[2:]:
+            w1 = _mix32_i32(w1 ^ _mix32_i32(jnp.asarray(s, jnp.int32)))
+        pltpu.prng_seed(w0, w1)
 
         def uniforms(shape, salt):
             del salt  # the hardware stream is sequential

@@ -1,102 +1,24 @@
-"""Version-compat shims for Pallas TPU API drift + kernel capability probing.
+"""Where a Pallas kernel runs: compiled by Mosaic on a TPU, interpreted
+elsewhere.
 
-The Pallas TPU namespace renamed several symbols across jax releases
-(``TPUCompilerParams`` -> ``CompilerParams``, and the older
-``dimension_semantics=`` kwarg moved between positional conventions).  Every
-kernel in this repo goes through this module instead of touching
-``pltpu.CompilerParams`` directly, so a jax upgrade is a one-file change.
-
-Resolved at import time (cheap, and failures surface immediately):
-
-  * :data:`CompilerParams`  — the compiler-params class for ``pallas_call``.
-  * :func:`compiler_params` — build a params object from keyword arguments,
-    dropping kwargs the installed class does not know about (forward/backward
-    tolerant).
-
-Plus the ONE capability helper every kernel wrapper queries
-(:func:`kernel_caps`), consolidating two orthogonal detections:
-
-  * **interpret** — off-TPU backends run ``pallas_call(interpret=True)``,
-    which is how CPU CI exercises every kernel (flash_attn, paged_attn,
-    bitplane_mac, imc_mac, rbl_decode) on each PR instead of only on TPU.
-  * **prng**      — whether an in-kernel PRNG is usable for the noisy
-    kernels.  The interpreter has NO lowering for the Mosaic hardware PRNG
-    (``pltpu.prng_seed`` raises ``NotImplementedError`` on CPU), so
-    interpret-mode kernels fall back to a stateless counter-hash PRNG
-    (:func:`repro.kernels.common.make_normal_sampler`) which runs anywhere;
-    the compiled TPU path requires the ``pltpu.prng_seed`` /
-    ``prng_random_bits`` primitives.  ``prng=False`` therefore only happens
-    on a compiled-TPU build of jax too old to expose them — the one case
-    where a noisy kernel wrapper must warn and fall back to the jnp engine.
+Every kernel wrapper resolves its ``interpret`` argument here.  Off the TPU
+the Pallas interpreter runs the kernel body, bit-faithfully, which is how the
+CPU tests exercise every kernel (flash_attn, paged_attn, bitplane_mac,
+imc_mac, rbl_decode).  On a TPU every kernel compiles: an interpreted kernel
+there would be a slow stand-in that hides what the device does.
 """
 from __future__ import annotations
 
-import inspect
-from dataclasses import dataclass
-
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.7 exposes ``CompilerParams``; 0.4.x-0.6.x call it
-# ``TPUCompilerParams``.  Resolve whichever exists.
-if hasattr(pltpu, "CompilerParams"):
-    CompilerParams = pltpu.CompilerParams
-elif hasattr(pltpu, "TPUCompilerParams"):
-    CompilerParams = pltpu.TPUCompilerParams
-else:  # pragma: no cover - ancient jax; kernels would not work anyway
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is unsupported")
-
-_ACCEPTED = frozenset(inspect.signature(CompilerParams).parameters)
-
-# Mosaic hardware PRNG primitives (the compiled-TPU noisy fast path).
-HAS_TPU_PRNG = (hasattr(pltpu, "prng_seed")
-                and hasattr(pltpu, "prng_random_bits"))
-
-
-def compiler_params(**kw):
-    """``CompilerParams(**kw)`` with unknown kwargs silently dropped.
-
-    Lets call-sites pass the superset of tuning knobs they want; whatever the
-    installed jax supports takes effect.
-    """
-    return CompilerParams(**{k: v for k, v in kw.items() if k in _ACCEPTED})
-
-
-@dataclass(frozen=True)
-class KernelCaps:
-    """What the resolved execution mode of a kernel can do.
-
-    interpret — this call runs through the Pallas interpreter.
-    prng      — an in-kernel PRNG is available for noisy kernels: always in
-                interpret mode (counter-hash fallback), and in compiled mode
-                iff the installed jax exposes the Mosaic PRNG primitives.
-    """
-
-    interpret: bool
-    prng: bool
-
-
-def kernel_caps(interpret: bool | None = None) -> KernelCaps:
-    """Resolve one kernel call's capabilities (the five ops.py entry points).
-
-    ``interpret=None`` defers to :func:`default_interpret`; an explicit bool
-    wins.  PRNG capability is derived from the SAME resolution, so interpret
-    detection and PRNG detection can never disagree about which engine a
-    noisy call actually runs on.
-    """
-    it = default_interpret() if interpret is None else interpret
-    return KernelCaps(interpret=it, prng=it or HAS_TPU_PRNG)
-
-
-def default_interpret() -> bool:
-    """True off-TPU: Mosaic only targets TPU, so every other backend runs the
-    kernels through the Pallas interpreter (bit-faithful, portable CI)."""
-    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """The ``interpret=None`` convention shared by all kernel ``ops`` wrappers:
-    ``None`` defers to :func:`default_interpret`, an explicit bool wins."""
-    return kernel_caps(interpret).interpret
+    """``None`` interprets exactly off the TPU; an explicit bool wins, except
+    that a TPU never interprets (raises ``ValueError``)."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas kernels compile on a TPU; interpret=True is "
+                         "for backends Mosaic does not target")
+    return interpret

@@ -6,7 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.compat import kernel_caps
+from repro.kernels.compat import resolve_interpret
 from repro.kernels.flash_attn.flash_attn import flash_attention_raw
 
 
@@ -20,7 +20,7 @@ def flash_attention(q, k, v, *, window: int = 0, bq: int = 128, bk: int = 128,
     flat (B*H, S, hd) panels).  S is padded to the block size; padded keys
     are masked inside the kernel via the valid-length closure.
     """
-    interpret = kernel_caps(interpret).interpret
+    interpret = resolve_interpret(interpret)
     b, s, h, hd = q.shape
     kv = k.shape[2]
     rep = h // kv

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _mac_kernel(a_ref, b_ref, o_ref, acc_ref):
     """One (bm, bn) output tile; accumulates over the K grid dimension."""
@@ -80,7 +78,7 @@ def imc_mac_raw(qa, qw, *, bm: int = 128, bn: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qa.astype(jnp.int8), qw.astype(jnp.int8))
@@ -112,7 +110,7 @@ def imc_mac_dequant_raw(qa, qw, scale_a, scale_w, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qa.astype(jnp.int8), qw.astype(jnp.int8),

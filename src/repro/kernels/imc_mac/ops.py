@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.imc_mac.imc_mac import imc_mac_dequant_raw, imc_mac_raw
-from repro.kernels.compat import kernel_caps
+from repro.kernels.compat import resolve_interpret
 
 
 def _pad2(x, mult0, mult1):
@@ -29,7 +29,7 @@ def imc_mac(qa, qw, *, bm: int = 128, bn: int = 128, bk: int = 128,
 
     Leading batch dims of ``qa`` are flattened into M.
     """
-    interpret = kernel_caps(interpret).interpret
+    interpret = resolve_interpret(interpret)
     batch = qa.shape[:-1]
     m = 1
     for b in batch:
@@ -46,7 +46,7 @@ def imc_mac(qa, qw, *, bm: int = 128, bn: int = 128, bk: int = 128,
 def imc_mac_dequant(qa, qw, scale_a, scale_w, *, bm: int = 128, bn: int = 128,
                     bk: int = 128, interpret: bool | None = None):
     """Fused int8 GEMM + per-channel dequant -> float32."""
-    interpret = kernel_caps(interpret).interpret
+    interpret = resolve_interpret(interpret)
     batch = qa.shape[:-1]
     m = 1
     for b in batch:

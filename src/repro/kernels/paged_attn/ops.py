@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.kernels import autotune
-from repro.kernels.compat import kernel_caps
+from repro.kernels.compat import resolve_interpret
 from repro.kernels.paged_attn.paged_attn import paged_flash_decode_raw
 from repro.kernels.paged_attn.ref import paged_decode_ref
 
@@ -30,7 +30,7 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, *, k_scale=None,
     q: (B, 1, H, hd); k_pool/v_pool: (NB, bs, KV, hd) bf16/f32 or int8 with
     (NB, bs, KV) scale pools; block_table: (B, MB) int32 dense prefixes with
     ``-1`` sentinels; pos: (B,) int32 current positions.  ``interpret=None``
-    defers to :func:`repro.kernels.compat.default_interpret` (Pallas
+    defers to :func:`repro.kernels.compat.resolve_interpret` (Pallas
     interpreter off-TPU).  ``blocks_per_step=None`` takes the autotuner's
     cached winner for this shape bucket (pool panels DMA'd per grid step;
     bit-identical across values).  Returns (B, 1, H, hd) in q.dtype.
@@ -44,18 +44,18 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, *, k_scale=None,
     b, sq, h, hd = q.shape
     assert sq == 1, "paged flash decode is single-token"
     kv = k_pool.shape[2]
-    caps = kernel_caps(interpret)
+    interpret = resolve_interpret(interpret)
     if blocks_per_step is None:
         blocks_per_step = autotune.lookup(
             "paged_attn",
             {"b": b, "kv": kv, "rep": h // kv, "hd": hd,
              "bs": k_pool.shape[1], "mb": block_table.shape[1]},
             dtype="int8" if k_scale is not None else str(k_pool.dtype),
-            interpret=caps.interpret)["bps"]
+            interpret=interpret)["bps"]
     qg = q.reshape(b, kv, h // kv, hd)  # grouped heads, sq axis folded away
     out = paged_flash_decode_raw(
         qg, k_pool, v_pool, k_scale, v_scale,
         block_table.astype(jnp.int32), jnp.asarray(pos, jnp.int32),
         scale=hd ** -0.5, window=window, blocks_per_step=blocks_per_step,
-        interpret=caps.interpret)
+        interpret=interpret)
     return out.reshape(b, 1, h, hd)

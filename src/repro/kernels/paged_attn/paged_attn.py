@@ -9,17 +9,19 @@ Here attention reads the pools **directly** through the block table: the
 gathered K/V never exists in HBM, int8 blocks dequantize in-register, and
 sentinel (unallocated) table entries are skipped outright.
 
-Grid: ``(B, KV, ceil(MB / bps))`` — slot x kv-head x table-block-group, the
-block axis innermost ("arbitrary", carries the online-softmax state).  The
-block table and per-slot positions ride in via **scalar prefetch**
+Grid: ``(B, ceil(MB / bps))`` — slot x table-block-group, the block axis
+innermost ("arbitrary", carries the online-softmax state).  The block table
+and per-slot positions ride in via **scalar prefetch**
 (:class:`pltpu.PrefetchScalarGridSpec`), so each step's BlockSpec index maps
 resolve ``table[b, j*bps+t]`` *before* the body runs and DMA ``bps``
-``(block_size, hd)`` K and V panels from the pool into VMEM —
+``(block_size, KV, hd)`` K and V panels from the pool into VMEM —
 ``bps = blocks_per_step`` (autotuned, default 1) panel fetches in flight per
-step, statically unrolled in the body.
+step, statically unrolled in the body.  A panel holds every kv-head: Mosaic
+tiles a block's last two dims, which must be whole array dims (or multiples
+of (8, 128)), so the body walks the kv-heads of a panel in a static loop.
 
-Per ``(b, h)`` the scratch carries flash-decode state across ``j`` blocks
-(the m/l/acc pattern of ``kernels/flash_attn``):
+Per ``(b, kv-head)`` the scratch carries flash-decode state across ``j``
+blocks (the m/l/acc pattern of ``kernels/flash_attn``):
 
     s      = q_g k_j^T * scale        (rep x bs, MXU)
     m'     = max(m, rowmax(s))        (masked: ctx <= pos, sliding window)
@@ -48,13 +50,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
-def _make_kernel(bs: int, rep: int, scale: float, window: int, int8: bool,
-                 bps: int, mb: int):
+def _make_kernel(bs: int, kv: int, rep: int, scale: float, window: int,
+                 int8: bool, bps: int, mb: int):
     def kernel(tbl_ref, pos_ref, q_ref, *rest):
         k_refs = rest[0:bps]
         v_refs = rest[bps:2 * bps]
@@ -65,7 +65,7 @@ def _make_kernel(bs: int, rep: int, scale: float, window: int, int8: bool,
             idx += 2 * bps
         o_ref, m_ref, l_ref, acc_ref = rest[idx:idx + 4]
         b = pl.program_id(0)
-        j = pl.program_id(2)
+        j = pl.program_id(1)
 
         @pl.when(j == 0)
         def _init():
@@ -92,36 +92,40 @@ def _make_kernel(bs: int, rep: int, scale: float, window: int, int8: bool,
 
             @pl.when(live)
             def _block(t=t, base=base):
-                q = q_ref[0, 0].astype(jnp.float32)            # (rep, hd)
-                k = k_refs[t][0, :, 0].astype(jnp.float32)     # (bs, hd)
-                v = v_refs[t][0, :, 0].astype(jnp.float32)
-                if int8:  # in-register dequant against the scale pools
-                    k = k * ks_refs[t][0, :, 0].astype(jnp.float32)[:, None]
-                    v = v * vs_refs[t][0, :, 0].astype(jnp.float32)[:, None]
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
                 ctx = base + jax.lax.broadcasted_iota(jnp.int32, (rep, bs), 1)
                 valid = ctx <= pos
                 if window:
                     valid &= ctx > pos - window
-                s = jnp.where(valid, s, NEG_INF)
-                m_prev = m_ref[...]  # (rep, 1)
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-                l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
-                                                          keepdims=True)
-                acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_ref[...] = m_new
+                if int8:
+                    ks = ks_refs[t][0].astype(jnp.float32)  # (bs, KV)
+                    vs = vs_refs[t][0].astype(jnp.float32)
+                for h in range(kv):  # the panel holds every kv-head
+                    q = q_ref[0, h].astype(jnp.float32)        # (rep, hd)
+                    k = k_refs[t][0, :, h].astype(jnp.float32)  # (bs, hd)
+                    v = v_refs[t][0, :, h].astype(jnp.float32)
+                    if int8:  # in-register dequant against the scale pools
+                        k = k * ks[:, h:h + 1]
+                        v = v * vs[:, h:h + 1]
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                    s = jnp.where(valid, s, NEG_INF)
+                    m_prev = m_ref[h]  # (rep, 1)
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                    l_ref[h] = alpha * l_ref[h] + jnp.sum(
+                        p, axis=1, keepdims=True)
+                    acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                        p, v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    m_ref[h] = m_new
 
-        @pl.when(j == pl.num_programs(2) - 1)
+        @pl.when(j == pl.num_programs(1) - 1)
         def _flush():
-            o_ref[0, 0] = (acc_ref[...]
-                           / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...]
+                        / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
     return kernel
 
@@ -153,7 +157,7 @@ def paged_flash_decode_raw(q, k_pool, v_pool, k_scale, v_scale, block_table,
     mb = block_table.shape[1]
     int8 = k_scale is not None
     bps = max(1, min(blocks_per_step, mb))
-    grid = (b, kv, pl.cdiv(mb, bps))
+    grid = (b, pl.cdiv(mb, bps))
 
     def blk(tbl_ref, bi, ji):
         # Unallocated entries clamp to block 0: the DMA still lands (the
@@ -161,38 +165,39 @@ def paged_flash_decode_raw(q, k_pool, v_pool, k_scale, v_scale, block_table,
         # clamp guards the tail step when mb % bps != 0.
         return jnp.maximum(tbl_ref[bi, jnp.minimum(ji, mb - 1)], 0)
 
+    # Each panel spans the whole (KV, hd) tail of a pool block (see above).
     def kv_map(t):
-        return lambda b_, h, j, tbl, p: (blk(tbl, b_, j * bps + t), 0, h, 0)
+        return lambda b_, j, tbl, p: (blk(tbl, b_, j * bps + t), 0, 0, 0)
 
     def sc_map(t):
-        return lambda b_, h, j, tbl, p: (blk(tbl, b_, j * bps + t), 0, h)
+        return lambda b_, j, tbl, p: (blk(tbl, b_, j * bps + t), 0, 0)
 
-    q_spec = pl.BlockSpec((1, 1, rep, hd),
-                          lambda b_, h, j, t, p: (b_, h, 0, 0))
-    kv_specs = [pl.BlockSpec((1, bs, 1, hd), kv_map(t)) for t in range(bps)]
+    q_spec = pl.BlockSpec((1, kv, rep, hd), lambda b_, j, t, p: (b_, 0, 0, 0))
+    kv_specs = [pl.BlockSpec((1, bs, kv, hd), kv_map(t)) for t in range(bps)]
     in_specs = [q_spec] + kv_specs + kv_specs
     inputs = [q] + [k_pool] * bps + [v_pool] * bps
     if int8:
-        sc_specs = [pl.BlockSpec((1, bs, 1), sc_map(t)) for t in range(bps)]
+        sc_specs = [pl.BlockSpec((1, bs, kv), sc_map(t)) for t in range(bps)]
         in_specs += sc_specs + sc_specs
-        inputs += [k_scale] * bps + [v_scale] * bps
+        # Mosaic loads no f16 vectors; the scale pools are 1/hd of the pools.
+        ks, vs = k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)
+        inputs += [ks] * bps + [vs] * bps
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, hd),
-                               lambda b_, h, j, t, p: (b_, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, hd), jnp.float32),
+            pltpu.VMEM((kv, rep, 1), jnp.float32),
+            pltpu.VMEM((kv, rep, 1), jnp.float32),
+            pltpu.VMEM((kv, rep, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        _make_kernel(bs, rep, scale, window, int8, bps, mb),
+        _make_kernel(bs, kv, rep, scale, window, int8, bps, mb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, rep, hd), q.dtype),
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, pos, *inputs)

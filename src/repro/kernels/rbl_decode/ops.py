@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from repro.core import constants as C
 from repro.core.decoder import thresholds as core_thresholds
 from repro.kernels.rbl_decode.rbl_decode import rbl_decode_mac_raw
-from repro.kernels.compat import kernel_caps
+from repro.kernels.compat import resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "bm", "bn", "bk",
@@ -22,7 +22,7 @@ def rbl_decode_mac(a_bits, w_bits, thr=None, *, rows: int = C.ROWS,
     Leading batch dims of ``a_bits`` flatten into M.  ``thr`` defaults to the
     physics-model comparator references for ``rows`` (re-tunable, §IV-C).
     """
-    interpret = kernel_caps(interpret).interpret
+    interpret = resolve_interpret(interpret)
     if thr is None:
         thr = core_thresholds(rows, mode="physics")
     batch = a_bits.shape[:-1]

@@ -33,7 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import constants as C
 from repro.kernels.common import decode_counts
-from repro.kernels.compat import compiler_params
 
 
 def _make_kernel(rows: int, bk: int):
@@ -90,7 +89,7 @@ def rbl_decode_mac_raw(a_bits, w_bits, thresholds, *, rows: int = C.ROWS,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_bits.astype(jnp.int8), w_bits.astype(jnp.int8),

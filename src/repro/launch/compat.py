@@ -1,49 +1,20 @@
-"""Version-compat shims for the jax mesh-context API drift.
+"""The ambient-mesh API the launchers and model-side sharding hints use.
 
-The "make this mesh ambient" entry point moved three times across jax
-releases: 0.4.x enters the mesh itself as a context manager
-(``with mesh: ...``), 0.5.x-0.6.x grew ``jax.sharding.use_mesh``, and
-jax >= 0.6.2 promoted it to ``jax.set_mesh``.  The ambient-mesh *getter*
-drifted in lockstep (``jax.sharding.get_abstract_mesh`` vs the legacy
-``thread_resources`` env).  Every launcher and model-side sharding hint in
-this repo goes through this module — the sibling of
-:mod:`repro.kernels.compat` for the launch layer — so a jax upgrade stays a
-one-file change.
-
-Resolved at import time (cheap, and failures surface immediately):
-
-  * :func:`mesh_context`  — context manager installing ``mesh`` as ambient.
-  * :func:`ambient_mesh`  — the ambient (abstract or physical) mesh, or
-    ``None`` when no mesh context is active.
+  * :func:`mesh_context`  — context manager installing ``mesh`` as ambient
+    (``jax.set_mesh``).
+  * :func:`ambient_mesh`  — the ambient abstract mesh, or ``None`` when no
+    mesh context is active.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "set_mesh"):  # jax >= 0.6.2
-    mesh_context = jax.set_mesh
-elif hasattr(jax.sharding, "use_mesh"):  # 0.5.x - 0.6.x
-    mesh_context = jax.sharding.use_mesh
-else:  # 0.4.x: a Mesh is its own context manager
-
-    def mesh_context(mesh):
-        """``with mesh_context(mesh):`` — ambient-mesh install, any jax."""
-        return mesh
+mesh_context = jax.set_mesh
 
 
-if hasattr(jax.sharding, "get_abstract_mesh"):
-
-    def ambient_mesh():
-        """The mesh installed by :func:`mesh_context`, or None outside one."""
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return None
-        return mesh
-
-else:  # 0.4.x: the resource env carries the physical mesh
-    from jax._src import mesh as _mesh_lib
-
-    def ambient_mesh():
-        """The mesh installed by :func:`mesh_context`, or None outside one."""
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
+def ambient_mesh():
+    """The mesh installed by :func:`mesh_context`, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
+        return None
+    return mesh

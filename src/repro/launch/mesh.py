@@ -8,38 +8,46 @@ Production topology (TPU v5e):
   multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model")
 The "pod" axis carries pure DP (hierarchical gradient all-reduce over the
 slower cross-pod links); ZeRO/FSDP sharding stays intra-pod on "data".
+
+Every mesh has Auto axes: the model's ``shard_hint`` constraints are hints
+for the partitioner, which under Explicit axes (``jax.make_mesh``'s default)
+would instead be asserts on the operands' types.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes, over ``devices`` (default: all)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int | None = None, model_parallel: int = 2):
     """Small mesh over whatever devices exist (unit tests)."""
     n = n_devices or len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_submesh(devices, model_parallel: int = 2):
     """(data, model) mesh over an explicit device subset.
 
     The virtual-fleet coordinator partitions the local devices into per-host
-    groups; each group gets its own mesh built here (``jax.make_mesh`` always
-    spans ``jax.devices()``, so sub-meshes need the explicit constructor).
+    groups; each group gets its own mesh built here.
     """
-    import numpy as np
-    from jax.sharding import Mesh
-
     n = len(devices)
     mp = model_parallel if n % model_parallel == 0 else 1
-    return Mesh(np.asarray(devices).reshape(n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"), devices=devices)
 
 
 def partition_devices(n_hosts: int, devices=None):

@@ -6,9 +6,12 @@ batch-style ``run(requests)``) finished its deprecation cycle and is gone;
 typed ``submit``/``poll``/``drain`` API, with ragged admission, per-request
 budgets, and block-pool memory accounting on the ``kv="paged"`` path.
 
-    python -m repro.launch.serve --arch qwen2.5-3b --reduce --requests 6
-    python -m repro.launch.serve --arch qwen2.5-3b --reduce --requests 6 \
+    python -m repro.launch.serve --arch qwen2.5-3b --requests 6
+    python -m repro.launch.serve --arch qwen2.5-3b --requests 6 \
         --imc-mode sim --imc-noise-sigma 0.05 --seed 7
+
+``--reduce`` (the default) serves the same-family smoke variant;
+``--no-reduce`` serves the published widths.
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import numpy as np
 
 from repro.configs import get_config, reduce_config
 from repro.core.fabric import add_fabric_cli, apply_fabric_cli
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import Engine
 from repro.models.model import init_params
 from repro.runtime.straggler import StragglerMonitor
@@ -30,7 +34,9 @@ def main():
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--reduce", action="store_true", default=True)
+    ap.add_argument("--reduce", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the smoke variant (--no-reduce: full width)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -49,6 +55,7 @@ def main():
                          "hosts, round-robin requests, report merged SLOs")
     add_fabric_cli(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:

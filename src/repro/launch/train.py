@@ -20,6 +20,7 @@ from repro.configs import get_config, reduce_config
 from repro.configs.base import ShapeConfig
 from repro.core.fabric import add_fabric_cli, apply_fabric_cli
 from repro.data.pipeline import DataConfig, SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import Engine
 from repro.optim.adamw import AdamWConfig, init_adamw
 from repro.models.model import init_params
@@ -154,6 +155,7 @@ def main():
                          "count divisible by N)")
     add_fabric_cli(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:

@@ -13,17 +13,13 @@ Trials technique: replicating one operand row M times makes every output row
 an iid draw of the same decode distribution (noise is elementwise), so a
 single kernel launch yields M x N samples.
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.core.bitserial import bitserial_matmul_unsigned
 from repro.core.decoder import thresholds as core_thresholds
 from repro.core.rbl import rbl_voltage
-from repro.kernels.bitplane_mac import ops as bp_ops
 from repro.kernels.bitplane_mac.ops import bitplane_mac_noisy
 
 SIGMAS = dict(mismatch_sigma=0.3, comparator_offset_sigma=0.03)
@@ -239,31 +235,3 @@ def test_fabric_noisy_moment_parity_across_engines():
     assert abs(dk.mean() - dj.mean()) < 0.25 * s
     assert 0.75 < dk.std() / s < 1.33
 
-
-# ------------------------------------------------- PRNG-less fallback
-def test_fallback_warns_once_and_counts(monkeypatch):
-    from repro.kernels.compat import KernelCaps
-    from repro.telemetry import get_registry
-
-    monkeypatch.setattr(bp_ops, "kernel_caps",
-                        lambda it=None: KernelCaps(interpret=False,
-                                                   prng=False))
-    monkeypatch.setattr(bp_ops, "_WARNED_PRNG_FALLBACK", False)
-    ua, uw, _ = _trials(bits=4, m=8, k=16, n=4)
-    counter = get_registry().counter("bitplane_mac.noisy_jnp_fallback")
-    before = counter.value
-    with pytest.warns(RuntimeWarning, match="in-kernel PRNG"):
-        y1 = bitplane_mac_noisy(ua, uw, jax.random.key(0), bits_a=4,
-                                bits_w=4, **SIGMAS)
-    assert counter.value == before + 1
-    # engine switch, not a silent no-op: results match the jnp oracle bitwise
-    oracle = bitserial_matmul_unsigned(
-        ua, uw, bits_a=4, bits_w=4, mode="sim", key=jax.random.key(0),
-        rbl_mode="physics", **SIGMAS)
-    np.testing.assert_array_equal(np.asarray(y1), np.asarray(oracle))
-    # second call: counted again, but the warning fires only once
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        bitplane_mac_noisy(ua, uw, jax.random.key(0), bits_a=4, bits_w=4,
-                           **SIGMAS)
-    assert counter.value == before + 2

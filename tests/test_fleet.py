@@ -169,6 +169,10 @@ def test_fleet_serve_is_bit_identical_to_single_host_oracle(cfg, params):
     fsrv.drain()
     assert {h.host for h in fleet_handles} == {0, 1}, \
         "round-robin must actually use both hosts"
+    for h, srv in fsrv.servers.items():  # each host computes on its own
+        devs = set(coord.hosts()[h].devices)
+        for leaf in jax.tree.leaves((srv.params, srv.cache)):
+            assert leaf.devices() <= devs, (h, leaf.devices())
 
     # an odd wave size over 2 hosts alternates which host gets which
     # buckets, so warmup takes n_hosts waves; wave 3 must retrace nowhere

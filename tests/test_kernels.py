@@ -115,3 +115,16 @@ def test_rbl_decode_rows_16_physics():
     out = rbl_decode_mac(a, w, rows=16, bk=256, interpret=True)
     ref = rbl_decode_mac_ref(a, w, rows=16, mode="physics")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_interpret_resolves_from_the_platform(monkeypatch):
+    """Off the TPU kernels interpret by default; on a TPU they compile, and
+    an explicit request to interpret there is refused, not obeyed."""
+    from repro.kernels.compat import resolve_interpret
+
+    assert resolve_interpret(None) is True  # the test platform is the CPU
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="compile on a TPU"):
+        resolve_interpret(True)
