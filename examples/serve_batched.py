@@ -12,11 +12,14 @@ recompiles, which ``Engine.stats.traces`` pins down.
 Run:  PYTHONPATH=src python examples/serve_batched.py [--lengths 7,16,33]
 Add ``--imc-mode sim --imc-noise-sigma 0.05`` for a noisy fabric, or
 ``--kv ring`` for the legacy fixed-ring geometry (uniform lengths only).
-``--trace-out trace.json`` exports the run's prefill/decode spans as Chrome
-trace-event JSON — drop the file into https://ui.perfetto.dev to see the
-serving timeline; ``--telemetry`` prints the metric snapshot as markdown.
+``--trace-out DIR`` runs the serving under the JAX profiler and writes its
+trace to DIR: the Server's spans (admission, decode launch, wait, logits
+fetch, sampling) beside the device's ops, on one clock; drop
+``DIR/**/perfetto_trace.json.gz`` into https://ui.perfetto.dev to see the
+serving timeline.  ``--telemetry`` prints the metric snapshot as markdown.
 """
 import argparse
+import contextlib
 import time
 
 import jax
@@ -28,7 +31,7 @@ from repro.launch.engine import Engine
 from repro.launch.server import Request, Server
 from repro.models.model import init_params
 from repro.runtime.straggler import StragglerMonitor
-from repro.telemetry import (export_chrome_trace, serving_slos, to_markdown)
+from repro.telemetry import serving_slos, to_markdown
 
 
 def serve_fleet(cfg, params, rng, lengths, buckets, args):
@@ -72,8 +75,9 @@ def serve_fleet(cfg, params, rng, lengths, buckets, args):
           f"fleet traces {fleet.total_traces()} "
           f"(per host {fleet.traces_by_host()}), waves 2+ trace-free")
     print(f"merged SLOs (n_hosts={slos['n_hosts']}): ttft p50 "
-          f"{slos['ttft_ms']} ms, tpot p50 {slos['tpot_ms']} ms, peak "
-          f"block occupancy {slos['occupancy_peak']}")
+          f"{slos['ttft_ms']} ms, tpot p50 {slos['tpot_ms']} ms, queue p50 "
+          f"{slos['queue_ms']} ms, peak block occupancy "
+          f"{slos['occupancy_peak']}")
     if args.telemetry:
         print(to_markdown(registry=fleet.merged_registry()))
     print("serve_batched OK (fleet)")
@@ -96,9 +100,10 @@ def main():
                          "TPU, jnp elsewhere; pallas runs interpreted on CPU)")
     ap.add_argument("--block-size", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write prefill/decode spans as Chrome trace-event "
-                         "JSON (loadable in Perfetto / chrome://tracing)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="write a profiler trace of the serving to DIR: the "
+                         "Server's spans beside the device's ops, with a "
+                         "Perfetto-loadable perfetto_trace.json.gz")
     ap.add_argument("--telemetry", action="store_true",
                     help="print the telemetry snapshot as markdown tables")
     ap.add_argument("--fleet-hosts", type=int, default=1,
@@ -122,7 +127,13 @@ def main():
         return
 
     engine = Engine(noise_seed=args.seed, monitor=StragglerMonitor())
-    with engine.activate():
+    profile = contextlib.nullcontext()
+    if args.trace_out:  # no Python tracer: its events would bury the spans
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        profile = jax.profiler.trace(args.trace_out, create_perfetto_trace=True,
+                                     profiler_options=opts)
+    with profile, engine.activate():
         server = Server(cfg, params, engine=engine, slots=args.slots,
                         kv=args.kv, block_size=args.block_size,
                         buckets=buckets, attn_impl=args.attn_impl,
@@ -158,12 +169,13 @@ def main():
           f"traces, waves 2+ trace-free")
     slos = serving_slos(engine.registry, attn_impl=server.attn_impl)
     print(f"SLOs: ttft p50 {slos['ttft_ms']} ms, tpot p50 {slos['tpot_ms']} "
-          f"ms, peak block occupancy {slos['occupancy_peak']}")
+          f"ms, queue p50 {slos['queue_ms']} ms, peak block occupancy "
+          f"{slos['occupancy_peak']}")
     if args.telemetry:
         print(to_markdown(registry=engine.registry))
     if args.trace_out:
-        print(f"chrome trace -> {export_chrome_trace(args.trace_out)} "
-              f"(open in https://ui.perfetto.dev)")
+        print(f"profiler trace -> {args.trace_out} (open its "
+              f"perfetto_trace.json.gz in https://ui.perfetto.dev)")
     print("serve_batched OK")
 
 

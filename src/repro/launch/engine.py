@@ -30,11 +30,11 @@ PRNG-key plumbing for noisy fabrics.  The Engine owns them once:
     :attr:`swap_requests` for the serving/training loop to act on.
   * **telemetry** — cache hit/compile/trace counters land in a
     :class:`repro.telemetry.Registry` (``engine.cache_hits`` /
-    ``engine.compiles`` / ``engine.traces``), every cached step is wrapped in
-    a per-kind dispatch-time histogram (``engine.step_s.<kind>``; host-side
-    wall time around the jitted call — no block, no added sync), and AOT
-    lower/compile run under spans on the telemetry clock.  :attr:`stats`
-    remains the cheap in-process mirror the serve tests assert on.
+    ``engine.compiles`` / ``engine.traces``); every (re)trace of a cached
+    step runs under an ``engine.trace`` span (``kind``), and AOT
+    lower/compile under ``engine.aot.*`` spans, in the profiler's trace.
+    :attr:`stats` remains the cheap in-process mirror the serve tests
+    assert on.
 """
 from __future__ import annotations
 
@@ -125,33 +125,13 @@ class Engine:
                                   slot)
 
     # ------------------------------------------------- compiled-step cache
-    def _counted(self, fn):
+    def _counted(self, fn, kind: str):
         @functools.wraps(fn)
         def wrapper(*args):
             self.stats.traces += 1
             self.registry.counter("engine.traces").inc()
-            return fn(*args)
-
-        return wrapper
-
-    def _timed(self, fn, kind: str):
-        """Per-kind dispatch-time histogram around a jitted step.
-
-        Times the host-side call only — the step's result is NOT blocked on,
-        so no device sync is added; callers that want device-complete times
-        (the Server's decode loop) block themselves and feed
-        :meth:`observe_step_time`.
-        """
-        hist = self.registry.histogram(f"engine.step_s.{kind}")
-
-        @functools.wraps(fn)
-        def wrapper(*args):
-            if not self.registry.enabled:
+            with span("engine.trace", kind=kind):
                 return fn(*args)
-            t0 = clock()
-            out = fn(*args)
-            hist.observe(clock() - t0)
-            return out
 
         return wrapper
 
@@ -165,7 +145,7 @@ class Engine:
                autotune.geometry_token())
         step = self._steps.get(key)
         if step is None:
-            step = self._steps[key] = self._timed(build(), kind)
+            step = self._steps[key] = build()
             self.stats.compiles += 1
             self.registry.counter("engine.compiles").inc()
         else:
@@ -180,7 +160,8 @@ class Engine:
         donate_argnums = (0, 1) if donate else ()
         return self._cached_step(
             cfg, "train", (opt_cfg, donate),
-            lambda: jax.jit(self._counted(make_train_step(cfg, opt_cfg)),
+            lambda: jax.jit(self._counted(make_train_step(cfg, opt_cfg),
+                                            "train"),
                             donate_argnums=donate_argnums))
 
     def prefill_step(self, cfg: ModelConfig, max_new_tokens: int = 0,
@@ -198,7 +179,7 @@ class Engine:
         return self._cached_step(
             cfg, "prefill", extras,
             lambda: jax.jit(self._counted(
-                make_prefill_step(cfg, max_new_tokens))))
+                make_prefill_step(cfg, max_new_tokens), "prefill")))
 
     def decode_step(self, cfg: ModelConfig):
         """Jitted ``(params, cache, token, key) -> (logits, cache)``.
@@ -209,7 +190,7 @@ class Engine:
         """
         return self._cached_step(
             cfg, "decode", (),
-            lambda: jax.jit(self._counted(make_serve_step(cfg))))
+            lambda: jax.jit(self._counted(make_serve_step(cfg), "decode")))
 
     def admit_step(self, cfg: ModelConfig):
         """Jitted paged admission: ``(batch_cache, one_cache, table_row,
@@ -221,7 +202,7 @@ class Engine:
 
         return self._cached_step(
             cfg, "admit", (),
-            lambda: jax.jit(self._counted(merge_prefill_cache)))
+            lambda: jax.jit(self._counted(merge_prefill_cache, "admit")))
 
     # ------------------------------------------------------------ sharding
     def shard_params(self, cfg: ModelConfig, params):
@@ -267,23 +248,8 @@ class Engine:
         Returns hosts newly flagged for a hot-spare swap; they also
         accumulate in :attr:`swap_requests`.
         """
-        self.registry.histogram("engine.observed_step_s").observe(dt)
         if self.monitor is None:
             return []
         flagged = self.monitor.record_step({host: dt})
-        self.swap_requests.extend(flagged)
-        return flagged
-
-    def observe_step_times(self, times: Dict[int, float]) -> List[int]:
-        """Feed ONE step's per-host wall times (fleet path).
-
-        One ``record_step`` call with the full dict — per-host calls would
-        multiply the monitor's strike cadence by the fleet size.
-        """
-        for dt in times.values():
-            self.registry.histogram("engine.observed_step_s").observe(dt)
-        if self.monitor is None:
-            return []
-        flagged = self.monitor.record_step(dict(times))
         self.swap_requests.extend(flagged)
         return flagged

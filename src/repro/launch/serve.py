@@ -26,7 +26,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import Engine
 from repro.models.model import init_params
 from repro.runtime.straggler import StragglerMonitor
-from repro.telemetry import clock
+from repro.telemetry import clock, serving_slos
 
 
 def main():
@@ -91,7 +91,7 @@ def main():
             handles = [server.submit(r) for r in requests]
             server.drain()
         dt = clock() - t0
-        slos = None
+        slos = serving_slos(engine.registry, attn_impl=server.attn_impl)
         traces = engine.stats.traces
     ntok = sum(len(h.tokens) for h in handles)
     for h in handles:
@@ -99,10 +99,11 @@ def main():
     print(f"throughput: {ntok / max(dt, 1e-9):.1f} tok/s "
           f"({args.kv} lockstep decode, attn={server.attn_impl}; "
           f"{traces} traces)")
-    if slos is not None:
-        print(f"fleet SLOs (n_hosts={slos.get('n_hosts')}): "
-              f"ttft_ms={slos['ttft_ms']} tpot_ms={slos['tpot_ms']} "
-              f"occupancy_peak={slos['occupancy_peak']}")
+    where = f"fleet (n_hosts={slos['n_hosts']})" if "n_hosts" in slos \
+        else "server"
+    print(f"{where} SLOs p50: ttft_ms={slos['ttft_ms']} "
+          f"tpot_ms={slos['tpot_ms']} queue_ms={slos['queue_ms']} "
+          f"occupancy_peak={slos['occupancy_peak']}")
 
 
 if __name__ == "__main__":

@@ -33,14 +33,33 @@ sampling):
 
   * ``server.ttft_s``   — time-to-first-token histogram (submit -> the
     prefill-produced token).
+  * ``server.queue_wait_s`` — submit -> admission, per request: the part of
+    TTFT spent queued rather than prefilling.
   * ``server.tpot_s``   — time-per-output-token histogram (decode tokens
     only, per finished request).
   * ``server.admitted`` / ``server.rejected`` counters,
     ``server.queue_depth`` gauge.
   * ``server.block_occupancy`` gauge (+ high-water mark) fed from the
     :class:`~repro.models.kv_cache.BlockAllocator` free list.
-  * ``server.decode_tokens`` counter + ``server.decode_step_s`` histogram —
-    decode tokens/s is their ratio with :attr:`Server.decode_s`.
+  * ``server.decode_tokens`` counter + ``server.decode_step_s`` histogram
+    (with :attr:`Server.decode_s`, their ratio is decode tokens/s).
+
+Every host-side phase of a poll runs under a span (:func:`repro.telemetry
+.span`), written into the JAX profiler's trace beside the device's ops
+while a profiler session is active, so a gap in the device's work names
+the host work that caused it::
+
+    server.poll              (tick)         one per poll
+    ├─ server.admit          (rid, bucket, len)  one per admission
+    │  ├─ server.admit.launch   pad, uploads, prefill + merge dispatch
+    │  ├─ server.admit.wait     block on the prefill's logits
+    │  └─ server.admit.sample   (vocab,) logits to host, first token
+    ├─ server.decode         (tick, rows)   one per decode tick
+    │  ├─ server.decode.inputs    token array, table growth, key, uploads
+    │  ├─ server.decode.dispatch  the call into the jitted step
+    │  ├─ server.decode.wait      block on the step's logits
+    │  └─ server.decode.fetch     the rest of their copy to the host
+    └─ server.sample         (tick, rows)   per-row sampling, retire, gauges
 """
 from __future__ import annotations
 
@@ -166,9 +185,9 @@ class Server:
         self._m_decode_tokens = reg.counter("server.decode_tokens")
         self._m_queue = reg.gauge("server.queue_depth")
         self._m_occupancy = reg.gauge("server.block_occupancy")
-        self._m_tok_s = reg.gauge("server.decode_tokens_per_s")
         self._m_ttft = reg.histogram("server.ttft_s")
         self._m_tpot = reg.histogram("server.tpot_s")
+        self._m_queue_wait = reg.histogram("server.queue_wait_s")
         self._m_step = reg.histogram("server.decode_step_s")
 
     def _feed_gauges(self):
@@ -177,8 +196,6 @@ class Server:
         if self.kv == "paged":
             used = self.num_blocks - self.alloc.num_free
             self._m_occupancy.set(used / self.num_blocks)
-        if self.decode_s > 0:
-            self._m_tok_s.set(self._m_decode_tokens.value / self.decode_s)
 
     # ----------------------------------------------------------- public API
     def submit(self, request: Request) -> Handle:
@@ -221,20 +238,22 @@ class Server:
     def poll(self) -> List[Handle]:
         """Advance one tick (admit + one lockstep decode); returns handles
         that finished on this tick."""
-        self._pump()
-        self._feed_gauges()
-        if not any(self.active):
-            return []
-        try:
-            if self.decode_ticks in self._fail_at:
-                self._fail_at.discard(self.decode_ticks)
-                self.decode_ticks += 1
-                raise InjectedFailure(
-                    f"injected failure at decode tick {self.decode_ticks - 1}")
-            return self._step()
-        except InjectedFailure:
-            self._recover()
-            return []
+        with span("server.poll", tick=self.decode_ticks):
+            self._pump()
+            self._feed_gauges()
+            if not any(self.active):
+                return []
+            try:
+                if self.decode_ticks in self._fail_at:
+                    self._fail_at.discard(self.decode_ticks)
+                    self.decode_ticks += 1
+                    raise InjectedFailure(
+                        "injected failure at decode tick "
+                        f"{self.decode_ticks - 1}")
+                return self._step()
+            except InjectedFailure:
+                self._recover()
+                return []
 
     def drain(self) -> List[Handle]:
         """Serve every queued/active request to completion; returns all
@@ -289,21 +308,40 @@ class Server:
     def _admit(self, h: Handle, slot: int):
         req = h.request
         plen = len(req.prompt)
+        bucket = None if self.kv == "ring" else self._bucket_for(plen)
+        self._m_queue_wait.observe(clock() - h._t_submit)
+        with span("server.admit", rid=h.rid, bucket=bucket, len=plen):
+            with span("server.admit.launch"):
+                row = self._launch_prefill(req, slot, bucket)[0]
+            with span("server.admit.wait"):
+                self._await(row)
+            with span("server.admit.sample"):
+                h._rng = np.random.default_rng(req.seed)
+                h.tokens = [self._sample(h, np.asarray(row))]
+        h._t_first = clock()
+        self._m_ttft.observe(h._t_first - h._t_submit)
+        h._next_pos = plen
+        h.status, h.slot = "active", slot
+        self.active[slot] = h
+        if self._finished(h):
+            self._retire(h)
+
+    def _launch_prefill(self, req: Request, slot: int, bucket: Optional[int]):
+        """Upload the (padded) prompt, dispatch its prefill and the merge of
+        its cache into ``slot``; returns the prefill's logits, not waited
+        on."""
         prompt = np.asarray(req.prompt, np.int32)
         if self.kv == "paged":
-            bucket = self._bucket_for(plen)
             padded = np.zeros((bucket,), np.int32)
-            padded[:plen] = prompt
+            padded[:len(prompt)] = prompt
             batch = {"tokens": jnp.asarray(padded[None]),
-                     "length": jnp.asarray(plen, jnp.int32)}
+                     "length": jnp.asarray(len(prompt), jnp.int32)}
             table_row = jnp.asarray(self.alloc.table_row(slot))
         else:
             batch = {"tokens": jnp.asarray(prompt[None])}
             table_row = jnp.zeros((self.max_blocks,), jnp.int32)  # unused
-        bucket = None if self.kv == "ring" else len(padded)
-        with span("server.prefill", rid=h.rid, len=plen, bucket=bucket):
-            logits, cache1 = self._prefill_step(bucket)(
-                self.params, batch, self._next_key(slot))
+        logits, cache1 = self._prefill_step(bucket)(
+            self.params, batch, self._next_key(slot))
         if self.cache is None:
             if self.kv == "paged":
                 self.cache = init_paged_cache(cache1, self.slots,
@@ -314,15 +352,15 @@ class Server:
                     lambda o: broadcast_slots(o, self.slots), cache1)
         self.cache = self._admit_fn(self.cache, cache1, table_row,
                                     jnp.asarray(slot, jnp.int32))
-        h._rng = np.random.default_rng(req.seed)
-        h.tokens = [self._sample(h, np.asarray(logits[0]))]
-        h._t_first = clock()
-        self._m_ttft.observe(h._t_first - h._t_submit)
-        h._next_pos = plen
-        h.status, h.slot = "active", slot
-        self.active[slot] = h
-        if self._finished(h):
-            self._retire(h)
+        return logits
+
+    @staticmethod
+    def _await(x):
+        """Block until ``x`` is computed.  Its copy to the host is requested
+        first, so the copy starts as the step ends: waiting here adds no
+        round trip to the ``np.asarray`` that follows."""
+        x.copy_to_host_async()
+        x.block_until_ready()
 
     # --------------------------------------------------------------- decode
     def _sample(self, h: Handle, logits_row: np.ndarray) -> int:
@@ -350,43 +388,46 @@ class Server:
         self.active[h.slot] = None
 
     def _step(self) -> List[Handle]:
-        toks = np.zeros((self.slots, 1), np.int32)
-        for i, h in enumerate(self.active):
-            if h is not None:
-                toks[i, 0] = h.tokens[-1]
-                if self.kv == "paged":  # grow the table across a boundary
-                    while self.alloc.blocks_for(h._next_pos + 1) > \
-                            len(self.alloc.slot_blocks(i)):
-                        self.alloc.append(i)
-        t0 = clock()
-        with span("server.decode", tick=self.decode_ticks):
-            if self.kv == "paged":
-                logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    self._next_key(), jnp.asarray(self.alloc.table()))
-            else:
-                logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    self._next_key())
-            logits = np.asarray(logits)  # block on the step before timing it
-        dt = clock() - t0
-        self.decode_s += dt
-        self._m_step.observe(dt)
-        self.engine.observe_step_time(dt, host=self.host)
-        self.decode_ticks += 1
-        finished = []
-        n_active = 0
-        for i, h in enumerate(self.active):
-            if h is None:
-                continue
-            n_active += 1
-            h.tokens.append(self._sample(h, logits[i]))
-            h._next_pos += 1
-            if self._finished(h):
-                self._retire(h)
-                finished.append(h)
-        self._m_decode_tokens.inc(n_active)
-        self._feed_gauges()
+        rows = sum(h is not None for h in self.active)
+        tick = self.decode_ticks
+        with span("server.decode", tick=tick, rows=rows):
+            with span("server.decode.inputs"):
+                toks = np.zeros((self.slots, 1), np.int32)
+                for i, h in enumerate(self.active):
+                    if h is not None:
+                        toks[i, 0] = h.tokens[-1]
+                        if self.kv == "paged":  # grow across a boundary
+                            while self.alloc.blocks_for(h._next_pos + 1) > \
+                                    len(self.alloc.slot_blocks(i)):
+                                self.alloc.append(i)
+                t0 = clock()  # the step's time: key and uploads to fetch
+                args = (self.params, self.cache, jnp.asarray(toks),
+                        self._next_key())
+                if self.kv == "paged":
+                    args += (jnp.asarray(self.alloc.table()),)
+            with span("server.decode.dispatch"):
+                logits, self.cache = self._decode(*args)
+            with span("server.decode.wait"):
+                self._await(logits)
+            with span("server.decode.fetch"):
+                logits = np.asarray(logits)
+            dt = clock() - t0
+        with span("server.sample", tick=tick, rows=rows):
+            self.decode_s += dt
+            self._m_step.observe(dt)
+            self.engine.observe_step_time(dt, host=self.host)
+            self.decode_ticks += 1
+            finished = []
+            for i, h in enumerate(self.active):
+                if h is None:
+                    continue
+                h.tokens.append(self._sample(h, logits[i]))
+                h._next_pos += 1
+                if self._finished(h):
+                    self._retire(h)
+                    finished.append(h)
+            self._m_decode_tokens.inc(rows)
+            self._feed_gauges()
         return finished
 
     # -------------------------------------------------------------- faults

@@ -1,4 +1,4 @@
-"""Zero-dependency observability for the IMC engine/server stack.
+"""Observability for the IMC engine/server stack.
 
 Three small modules, one import surface:
 
@@ -6,9 +6,9 @@ Three small modules, one import surface:
              p50/p95/p99 summaries), a process-global Registry, and a
              disabled mode whose record path is a no-op branch.
   spans    — ``clock()`` (the runtime's one monotonic wall-clock source) and
-             nested span tracing exported as Perfetto-loadable Chrome
-             trace-event JSON.
-  export   — explicit JSON / markdown snapshots + BENCH_imc.json merge.
+             ``span()``: host regions written into the JAX profiler's trace,
+             on the device's clock, only while a profiler session runs.
+  export   — explicit JSON / markdown snapshots and the serving SLO row.
 
 The hard rule every instrumentation site obeys: **recording is host-side
 only** — no jax arrays, no device reads, no trace inputs — so telemetry can
@@ -16,16 +16,14 @@ never add a host<->device sync or a retrace to a compiled step.  The
 zero-steady-state-retrace serving guarantees hold with telemetry enabled
 (pinned by tests/test_telemetry.py).
 """
-from repro.telemetry.export import (merge_into_bench, serving_slos, snapshot,
-                                    to_markdown, write_json)
+from repro.telemetry.export import (serving_slos, snapshot, to_markdown,
+                                    write_json)
 from repro.telemetry.registry import (Counter, Gauge, Histogram, Registry,
                                       get_registry, set_enabled)
-from repro.telemetry.spans import (SpanRecorder, clock, export_chrome_trace,
-                                   get_recorder, span)
+from repro.telemetry.spans import clock, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "get_registry",
-    "set_enabled", "SpanRecorder", "clock", "export_chrome_trace",
-    "get_recorder", "span", "merge_into_bench", "serving_slos", "snapshot",
+    "set_enabled", "clock", "span", "serving_slos", "snapshot",
     "to_markdown", "write_json",
 ]

@@ -1,4 +1,4 @@
-"""Snapshot exporters: JSON dicts, markdown tables, and BENCH_imc.json merge.
+"""Snapshot exporters: JSON dicts, markdown tables, and the serving SLO row.
 
 Snapshots are explicit and pull-based — nothing here runs unless called, so
 the record path (see :mod:`repro.telemetry.registry`) stays write-only.  Three
@@ -7,10 +7,8 @@ consumers:
   * ``snapshot()``      — the raw {counters, gauges, histograms} dict
                           (JSON-serializable as-is).
   * ``to_markdown()``   — human-readable tables for CI job summaries / logs.
-  * ``merge_into_bench()`` — attach the snapshot to a ``BENCH_imc.json``
-                          record, so serve benches carry their TTFT/TPOT/
-                          occupancy alongside tokens/s and ``--compare``
-                          can diff them across runs.
+  * ``serving_slos()``  — the Server's latency and occupancy as flat row
+                          fields (ms), for bench rows and launcher output.
 """
 from __future__ import annotations
 
@@ -19,7 +17,7 @@ from typing import Dict, Optional
 
 from repro.telemetry.registry import Registry, get_registry
 
-__all__ = ["snapshot", "to_markdown", "merge_into_bench", "write_json"]
+__all__ = ["snapshot", "to_markdown", "serving_slos", "write_json"]
 
 
 def snapshot(registry: Optional[Registry] = None) -> Dict:
@@ -71,7 +69,9 @@ def to_markdown(snap: Optional[Dict] = None,
 def serving_slos(registry: Optional[Registry] = None,
                  attn_impl: Optional[str] = None,
                  n_hosts: Optional[int] = None) -> Dict:
-    """The serving SLO trio as flat row fields (ms units, JSON-friendly).
+    """The serving SLOs as flat row fields (ms units, JSON-friendly): p50
+    time to first token, time per output token and queue wait (submit to
+    admission), and the block pool's peak occupancy.
 
     Pulled from the Server's canonical metric names; absent metrics yield
     ``None`` so bench rows stay diffable across configurations that never
@@ -95,6 +95,7 @@ def serving_slos(registry: Optional[Registry] = None,
     occ = gauges.get("server.block_occupancy", {})
     slos = {"ttft_ms": p50("server.ttft_s"),
             "tpot_ms": p50("server.tpot_s"),
+            "queue_ms": p50("server.queue_wait_s"),
             "occupancy_peak": round(occ["hwm"], 3) if occ else None}
     if attn_impl is not None:
         slos["attn_impl"] = attn_impl
@@ -102,10 +103,3 @@ def serving_slos(registry: Optional[Registry] = None,
         slos["n_hosts"] = n_hosts
     return slos
 
-
-def merge_into_bench(record: Dict, registry: Optional[Registry] = None
-                     ) -> Dict:
-    """Attach the telemetry snapshot to a BENCH_imc.json-style record
-    (in place; returned for chaining)."""
-    record["telemetry"] = snapshot(registry)
-    return record

@@ -1,98 +1,43 @@
-"""Monotonic-clock span tracing, exported as Chrome trace-event JSON.
+"""The runtime's one wall clock, and host spans on the profiler's clock.
 
 ``clock()`` is the ONE wall-clock source for the whole runtime
 (``time.perf_counter``: monotonic, high-resolution, immune to NTP steps —
 ``time.time`` is neither).  Every timing site in the Engine/Server/runtime
 loops goes through it, so durations are comparable across modules.
 
-Spans are host-side begin/end pairs around interesting regions (AOT lower /
-compile, prefill, decode ticks).  They nest naturally — the recorder emits
-Chrome "complete" (``ph="X"``) events whose containment on a thread's
-timeline encodes the hierarchy — and the JSON loads directly in Perfetto
-(https://ui.perfetto.dev) or ``chrome://tracing``:
+``span(name, **args)`` marks a host region in the JAX profiler's trace: it
+is a ``jax.profiler.TraceAnnotation``, so the region lands in the same
+``.xplane.pb`` as the device's operations, on the same clock, and a gap in
+the device's work can be read against what the host was doing meanwhile.
+Spans nest by containment on the host thread's timeline; ``args`` become
+the event's stats (keep them to ints and short names)::
 
-    with span("engine.aot.lower", arch=cfg.name):
-        lowered = jitted.lower(*specs)
-    export_chrome_trace("trace.json")
+    with span("server.decode", tick=3, rows=8):
+        ...
 
-Like metrics, spans obey the owning :class:`~repro.telemetry.registry
-.Registry`'s ``enabled`` flag: disabled, ``span()`` yields without recording
-(one branch, no allocation), so hot decode loops can keep their spans.
+A span records only while a profiler session is active
+(``jax.profiler.start_trace`` / ``jax.profiler.trace``).  Otherwise it is
+one check and a shared null context — no args are formatted, nothing is
+kept — so hot decode loops keep their spans.  Spans do not follow
+:attr:`Registry.enabled`; counters and histograms do.  The profiler writes
+the Perfetto view (``jax.profiler.trace(dir, create_perfetto_trace=True)``).
 """
 from __future__ import annotations
 
 import contextlib
-import json
-import threading
 import time
-from typing import Dict, List, Optional
 
-from repro.telemetry.registry import Registry, get_registry
+from jax.profiler import TraceAnnotation
 
-__all__ = ["clock", "SpanRecorder", "get_recorder", "span",
-           "export_chrome_trace"]
+__all__ = ["clock", "span"]
 
 clock = time.perf_counter
 
-
-class SpanRecorder:
-    """Collects complete-span events; one recorder per registry by default."""
-
-    def __init__(self, registry: Optional[Registry] = None):
-        self.registry = registry or get_registry()
-        self.events: List[Dict] = []
-        self._tls = threading.local()
-
-    def _depth(self) -> int:
-        return getattr(self._tls, "depth", 0)
-
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        if not self.registry.enabled:
-            yield
-            return
-        self._tls.depth = self._depth() + 1
-        t0 = clock()
-        try:
-            yield
-        finally:
-            dur = clock() - t0
-            self._tls.depth -= 1
-            ev = {"name": name, "ph": "X", "cat": "repro",
-                  "ts": t0 * 1e6, "dur": dur * 1e6,
-                  "pid": 0, "tid": threading.get_ident()}
-            if args:
-                ev["args"] = {k: str(v) for k, v in args.items()}
-            self.events.append(ev)
-
-    def clear(self) -> None:
-        self.events.clear()
-
-    def chrome_trace(self) -> Dict:
-        """The Chrome trace-event JSON object (Perfetto-loadable)."""
-        return {"traceEvents": sorted(self.events, key=lambda e: e["ts"]),
-                "displayTimeUnit": "ms"}
-
-    def export(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return path
-
-
-_GLOBAL = SpanRecorder()
-
-
-def get_recorder() -> SpanRecorder:
-    """The process-global recorder (paired with the global registry)."""
-    return _GLOBAL
+_OFF = contextlib.nullcontext()
 
 
 def span(name: str, **args):
-    """Span on the global recorder: ``with span("server.prefill", bucket=16)``."""
-    return _GLOBAL.span(name, **args)
-
-
-def export_chrome_trace(path: str, recorder: Optional[SpanRecorder] = None
-                        ) -> str:
-    """Write the recorded spans as Chrome trace-event JSON; returns ``path``."""
-    return (recorder or _GLOBAL).export(path)
+    """``with span("server.admit", rid=4, bucket=128, len=57): ...``"""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **args)
+    return _OFF

@@ -1,12 +1,15 @@
 """Telemetry subsystem tests.
 
 Registry mechanics (histogram percentile accuracy, disabled-mode no-ops and
-their cost), span recording + Chrome trace export, snapshot/markdown/bench
-exporters, the straggler monitor's true-median regression, and the serving
-SLO integration: TTFT/TPOT/occupancy recorded on the mixed-length ragged
-schedule WITHOUT breaking the zero-steady-state-retrace guarantee.
+their cost), spans on the profiler's clock (what a Server writes into a
+profiler trace, and the cost of a span with no profiler session),
+snapshot/markdown exporters, the straggler monitor's true-median regression,
+and the serving SLO integration: TTFT/TPOT/queue wait/occupancy recorded on
+the mixed-length ragged schedule WITHOUT breaking the
+zero-steady-state-retrace guarantee.
 """
-import json
+import glob
+import os
 
 import jax
 import numpy as np
@@ -17,9 +20,8 @@ from repro.launch.engine import Engine
 from repro.launch.server import Request, Server
 from repro.models.model import init_params
 from repro.runtime.straggler import StragglerConfig, StragglerMonitor, _median
-from repro.telemetry import (Registry, SpanRecorder, clock, get_registry,
-                             merge_into_bench, serving_slos, snapshot,
-                             to_markdown)
+from repro.telemetry import (Registry, clock, get_registry, serving_slos,
+                             snapshot, span, to_markdown)
 
 LENGTHS = (7, 16, 33, 12, 5)  # same ragged schedule the paged-KV tests pin
 MAX_NEW = 6
@@ -89,8 +91,6 @@ def test_disabled_mode_records_nothing():
         c.inc(100)
         g.set(9.0)
         h.observe(0.5)
-        with SpanRecorder(reg).span("quiet"):
-            pass
     assert reg.enabled  # context restores the flag
     assert c.value == 1 and g.value == 0.0 and h.count == 1
     off = Registry(enabled=False)
@@ -131,41 +131,115 @@ def test_disabled_record_path_is_cheap_enough_for_decode_loops():
 
 
 # ------------------------------------------------------------------- spans
-def test_spans_nest_and_export_chrome_trace(tmp_path):
+SPAN_TREE = {  # span -> the span it nests in
+    "server.admit": "server.poll",
+    "server.admit.launch": "server.admit",
+    "server.admit.wait": "server.admit",
+    "server.admit.sample": "server.admit",
+    "server.decode": "server.poll",
+    "server.decode.inputs": "server.decode",
+    "server.decode.dispatch": "server.decode",
+    "server.decode.wait": "server.decode",
+    "server.decode.fetch": "server.decode",
+    "server.sample": "server.poll",
+}
+
+
+def _host_spans(trace_dir):
+    """Every ``server.*`` / ``engine.*`` event of the trace's host planes:
+    (name, start_ns, end_ns, stats)."""
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("server.", "engine.")):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                {k: v for k, v in ev.stats}))
+    return out
+
+
+def test_server_spans_share_the_profiler_clock(cfg, params, tmp_path):
+    """A Server's spans land in the profiler's own trace: every span of the
+    poll tree, each inside its parent, with its args as stats."""
     reg = Registry()
-    rec = SpanRecorder(reg)
-    with rec.span("outer", phase="a"):
-        with rec.span("inner"):
+    eng = Engine(registry=reg)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with eng.activate():
+            server = Server(cfg, params, engine=eng, slots=2, block_size=8,
+                            buckets=(16,), max_seq_len=16 + 4)
+            for n in (7, 12):
+                server.submit(Request(np.arange(n, dtype=np.int32) % 50,
+                                      max_new_tokens=4))
+            server.drain()
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    names = {s[0] for s in spans}
+    assert set(SPAN_TREE) | {"server.poll", "engine.trace"} <= names, names
+    polls = [s for s in spans if s[0] == "server.poll"]
+    for name, start, end, _ in spans:
+        if name not in SPAN_TREE:
+            continue
+        parents = [p for p in spans if p[0] == SPAN_TREE[name]
+                   and p[1] <= start and end <= p[2]]
+        assert parents, f"{name} at {start} lies in no {SPAN_TREE[name]}"
+        if name.startswith("server.decode."):  # ... and that in a poll
+            d = parents[0]
+            assert any(p[1] <= d[1] and d[2] <= p[2] for p in polls)
+    admits = [s[3] for s in spans if s[0] == "server.admit"]
+    assert sorted(a["rid"] for a in admits) == [0, 1]
+    assert {a["bucket"] for a in admits} == {16}
+    assert sorted(a["len"] for a in admits) == [7, 12]
+    ticks = [s[3]["tick"] for s in spans if s[0] == "server.decode"]
+    assert ticks == list(range(len(ticks))) and ticks
+    assert {s[3]["kind"] for s in spans if s[0] == "engine.trace"} >= \
+        {"prefill", "admit", "decode"}
+
+
+def test_span_without_a_profiler_session_is_cheap_and_records_nothing(
+        tmp_path):
+    """With no profiler session a span is one check: well under 2 us, and
+    nothing of it reaches a trace started afterwards."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    n = 100_000
+    t0 = clock()
+    for i in range(n):
+        with span("test.quiet", tick=i, rows=8):
             pass
-    assert [e["name"] for e in rec.events] == ["inner", "outer"]
-    trace = rec.chrome_trace()
-    # chronological order + the complete-event shape Perfetto expects
-    assert [e["name"] for e in trace["traceEvents"]] == ["outer", "inner"]
-    outer, inner = trace["traceEvents"]
-    assert outer["ph"] == "X" and outer["args"] == {"phase": "a"}
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
-    path = rec.export(str(tmp_path / "trace.json"))
-    loaded = json.load(open(path))
-    assert loaded["traceEvents"] and loaded["displayTimeUnit"] == "ms"
+    per_span = (clock() - t0) / n
+    assert per_span < 2e-6, f"span with the profiler off: {per_span * 1e6} us"
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("engine.marker"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [s[0] for s in _host_spans(str(tmp_path))] == ["engine.marker"]
 
 
 # --------------------------------------------------------------- exporters
-def test_markdown_and_bench_merge_round_trip():
+def test_markdown_lists_counters_gauges_and_histograms():
     reg = Registry()
     reg.counter("server.admitted").inc(4)
     reg.gauge("server.queue_depth").set(2)
     reg.histogram("server.ttft_s").observe(0.05)
     md = to_markdown(registry=reg)
     assert "server.admitted" in md and "server.ttft_s" in md
-    rec = merge_into_bench({"tokens_per_s": 10.0}, reg)
-    assert rec["telemetry"]["counters"]["server.admitted"] == 4
-    json.dumps(rec)  # BENCH_imc.json-serializable as-is
+    assert "server.queue_depth" in md and "| 50 |" in md
 
 
 def test_serving_slos_are_none_without_a_server():
     slos = serving_slos(Registry())
-    assert slos == {"ttft_ms": None, "tpot_ms": None, "occupancy_peak": None}
+    assert slos == {"ttft_ms": None, "tpot_ms": None, "queue_ms": None,
+                    "occupancy_peak": None}
 
 
 # ----------------------------------------------------------- registry merge
@@ -304,14 +378,14 @@ def test_server_slos_on_ragged_schedule_without_retraces(cfg, params):
     assert 0.0 < occ["hwm"] <= 1.0
     assert occ["value"] == 0.0, "drained pool must read empty"
     assert snap["counters"]["server.decode_tokens"] == n * (MAX_NEW - 1)
-    assert snap["gauges"]["server.decode_tokens_per_s"]["value"] > 0
+    assert snap["histograms"]["server.queue_wait_s"]["count"] == n
 
     slos = serving_slos(reg)
     assert slos["ttft_ms"] > 0 and slos["tpot_ms"] > 0
+    assert slos["queue_ms"] is not None and slos["queue_ms"] <= slos["ttft_ms"]
     assert slos["occupancy_peak"] == round(occ["hwm"], 3)
     # engine-side instrumentation rode along on the same registry
     assert snap["counters"]["engine.compiles"] >= 3
-    assert snap["histograms"]["engine.step_s.decode"]["count"] > 0
 
 
 def test_global_registry_is_the_default_feed():
