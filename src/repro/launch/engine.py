@@ -59,6 +59,11 @@ from repro.runtime.straggler import StragglerMonitor
 from repro.telemetry import Registry, clock, get_registry, span
 
 
+@jax.jit
+def _fold_key(base, step, slot):
+    return jax.random.fold_in(jax.random.fold_in(base, step), slot)
+
+
 @dataclass
 class AotResult:
     """One dry-run cell: the lowered/compiled step and how long each took."""
@@ -117,12 +122,12 @@ class Engine:
 
         Deterministic in ``noise_seed`` — two Engines with the same seed
         replay identical noise streams (the seed-reproducibility contract
-        the noisy-serve tests pin down).
+        the noisy-serve tests pin down).  One compiled call rather than
+        two eager ``fold_in`` dispatches: every serving tick makes it.
         """
         if self._base_key is None:
             self._base_key = jax.random.key(self.noise_seed)
-        return jax.random.fold_in(jax.random.fold_in(self._base_key, step),
-                                  slot)
+        return _fold_key(self._base_key, step, slot)
 
     # ------------------------------------------------- compiled-step cache
     def _counted(self, fn, kind: str):

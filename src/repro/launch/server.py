@@ -55,11 +55,20 @@ the host work that caused it::
     │  ├─ server.admit.wait     block on the prefill's logits
     │  └─ server.admit.sample   (vocab,) logits to host, first token
     ├─ server.decode         (tick, rows)   one per decode tick
-    │  ├─ server.decode.inputs    token array, table growth, key, uploads
-    │  ├─ server.decode.dispatch  the call into the jitted step
-    │  ├─ server.decode.wait      block on the step's logits
+    │  ├─ server.decode.inputs    table growth, key, uploads if needed
+    │  ├─ server.decode.dispatch  the jitted step, then its argmax
+    │  ├─ server.decode.wait      block on the step's ids
     │  └─ server.decode.fetch     the rest of their copy to the host
-    └─ server.sample         (tick, rows)   per-row sampling, retire, gauges
+    └─ server.sample         (tick, rows)   per-row tokens, retire, gauges
+
+A decode tick keeps greedy rows on the device: :func:`greedy_ids` takes
+the argmax of the step's logits there, the host reads back one int32 per
+slot, and those ids are the next tick's token input as they stand.  The
+host builds and uploads a token array only on a tick after some row's last
+token was chosen on the host (an admission's first token, or a row sampled
+with ``temperature > 0``, whose logits alone go to the host), and the block
+table only when it changed.  Counters: ``server.decode.token_uploads``,
+``server.decode.host_rows``, ``server.decode.table_uploads``.
 """
 from __future__ import annotations
 
@@ -70,12 +79,19 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.launch.engine import Engine
 from repro.models.kv_cache import (BlockAllocator, broadcast_slots,
                                    init_paged_cache)
 from repro.runtime.fault_tolerance import InjectedFailure
 from repro.telemetry import clock, span
+
+
+def greedy_ids(logits):
+    """``(slots, vocab)`` logits -> ``(slots, 1)`` int32 argmax ids (first
+    index on ties, as ``np.argmax``): the decode step's token input."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
 
 
 @dataclass(frozen=True)
@@ -178,6 +194,13 @@ class Server:
         self._decode = self.engine.decode_step(cfg)
         self._admit_fn = self.engine.admit_step(cfg)
         self._prefills: Dict[int, object] = {}
+        # Token inputs, whether the last tick's ids or a host upload, sit
+        # replicated on the Engine's mesh: one placement, one trace.
+        self._tok_at = NamedSharding(self.engine.mesh, PartitionSpec())
+        self._ids = jax.jit(greedy_ids, out_shardings=self._tok_at)
+        self._tok = None  # the last tick's ids, on the device
+        self._tok_host = True  # some row's last token came from the host
+        self._table = None  # the last block table uploaded: (host, device)
         reg = self.engine.registry
         self._m_admitted = reg.counter("server.admitted")
         self._m_rejected = reg.counter("server.rejected")
@@ -189,6 +212,9 @@ class Server:
         self._m_tpot = reg.histogram("server.tpot_s")
         self._m_queue_wait = reg.histogram("server.queue_wait_s")
         self._m_step = reg.histogram("server.decode_step_s")
+        self._m_tok_uploads = reg.counter("server.decode.token_uploads")
+        self._m_host_rows = reg.counter("server.decode.host_rows")
+        self._m_table_uploads = reg.counter("server.decode.table_uploads")
 
     def _feed_gauges(self):
         """Occupancy from the allocator's free list + queue depth (host ints)."""
@@ -323,6 +349,7 @@ class Server:
         h._next_pos = plen
         h.status, h.slot = "active", slot
         self.active[slot] = h
+        self._tok_host = True
         if self._finished(h):
             self._retire(h)
 
@@ -387,30 +414,60 @@ class Server:
             self.alloc.release(h.slot)
         self.active[h.slot] = None
 
+    def _tokens(self):
+        """The tick's ``(slots, 1)`` token input: the last tick's ids as
+        they stand on the device, unless some row's last token was chosen
+        on the host.  A free slot's row carries a stale id; its KV writes go
+        where the table sends them, as a live row's would."""
+        if not self._tok_host:
+            return self._tok
+        toks = np.zeros((self.slots, 1), np.int32)
+        for i, h in enumerate(self.active):
+            if h is not None:
+                toks[i, 0] = h.tokens[-1]
+        self._tok_host = False
+        self._m_tok_uploads.inc()
+        return jax.device_put(toks, self._tok_at)
+
+    def _block_table(self):
+        """The paged block table on the device, uploaded when it changed
+        (a block appended, a request admitted or retired)."""
+        table = self.alloc.table()
+        if self._table is None or not np.array_equal(table, self._table[0]):
+            self._table = (table, jnp.asarray(table))
+            self._m_table_uploads.inc()
+        return self._table[1]
+
     def _step(self) -> List[Handle]:
         rows = sum(h is not None for h in self.active)
         tick = self.decode_ticks
+        # rows sampled with a temperature read their logits on the host
+        hot = [i for i, h in enumerate(self.active)
+               if h is not None and h.request.temperature > 0]
         with span("server.decode", tick=tick, rows=rows):
             with span("server.decode.inputs"):
-                toks = np.zeros((self.slots, 1), np.int32)
-                for i, h in enumerate(self.active):
-                    if h is not None:
-                        toks[i, 0] = h.tokens[-1]
-                        if self.kv == "paged":  # grow across a boundary
-                            while self.alloc.blocks_for(h._next_pos + 1) > \
-                                    len(self.alloc.slot_blocks(i)):
-                                self.alloc.append(i)
+                if self.kv == "paged":  # grow across a block boundary
+                    for i, h in enumerate(self.active):
+                        while h is not None and \
+                                self.alloc.blocks_for(h._next_pos + 1) > \
+                                len(self.alloc.slot_blocks(i)):
+                            self.alloc.append(i)
                 t0 = clock()  # the step's time: key and uploads to fetch
-                args = (self.params, self.cache, jnp.asarray(toks),
+                args = (self.params, self.cache, self._tokens(),
                         self._next_key())
                 if self.kv == "paged":
-                    args += (jnp.asarray(self.alloc.table()),)
+                    args += (self._block_table(),)
             with span("server.decode.dispatch"):
                 logits, self.cache = self._decode(*args)
+                self._tok = self._ids(logits)
             with span("server.decode.wait"):
-                self._await(logits)
+                if hot:
+                    logits.copy_to_host_async()
+                self._await(self._tok)
             with span("server.decode.fetch"):
-                logits = np.asarray(logits)
+                ids = np.asarray(self._tok)
+                if hot:
+                    logits = np.asarray(logits)
             dt = clock() - t0
         with span("server.sample", tick=tick, rows=rows):
             self.decode_s += dt
@@ -421,11 +478,15 @@ class Server:
             for i, h in enumerate(self.active):
                 if h is None:
                     continue
-                h.tokens.append(self._sample(h, logits[i]))
+                h.tokens.append(self._sample(h, logits[i]) if i in hot
+                                else int(ids[i, 0]))
                 h._next_pos += 1
                 if self._finished(h):
                     self._retire(h)
                     finished.append(h)
+            if hot:
+                self._tok_host = True
+                self._m_host_rows.inc(len(hot))
             self._m_decode_tokens.inc(rows)
             self._feed_gauges()
         return finished

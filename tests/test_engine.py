@@ -172,6 +172,16 @@ def _noisy_cfg(cfg):
     return dataclasses.replace(cfg, fabric=spec, imc_mode="off")
 
 
+@pytest.mark.parametrize("step,slot", [(0, 0), (1, 0), (3, 5), (4097, 7)])
+def test_noise_key_is_the_two_fold_ins(step, slot):
+    """One compiled call gives the bits of fold_in(fold_in(base, step), slot)."""
+    got = Engine(noise_seed=42).noise_key(step, slot)
+    want = jax.random.fold_in(jax.random.fold_in(jax.random.key(42), step),
+                              slot)
+    np.testing.assert_array_equal(jax.random.key_data(got),
+                                  jax.random.key_data(want))
+
+
 def test_noisy_serve_keys_thread_through_jit(cfg, params):
     ncfg = _noisy_cfg(cfg)
     prompt = np.arange(PROMPT, dtype=np.int32)[None] % ncfg.vocab_size

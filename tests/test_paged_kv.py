@@ -7,7 +7,10 @@ zero steady-state recompiles.  Around it: BlockAllocator invariants
 per-request termination (``max_new_tokens`` / ``eos_id``) with early block
 release, admission rejection, and fault re-queue determinism.  A hypothesis
 property test (skipped when hypothesis is absent) drives random admit/grow/
-finish schedules and asserts no block is ever double-assigned.
+finish schedules and asserts no block is ever double-assigned.  Decode
+ticks chain greedy ids on the device: those streams, rows sampled on the
+host beside them, and the counters of what crossed to the host are pinned
+at the end.
 """
 import jax
 import jax.numpy as jnp
@@ -19,6 +22,7 @@ from repro.launch.engine import Engine
 from repro.launch.server import Request, Server
 from repro.models.kv_cache import BlockAllocator, OutOfBlocks
 from repro.models.model import decode_step, init_params, prefill
+from repro.telemetry import Registry
 
 LENGTHS = (7, 16, 33, 12, 5)  # straddles the 16/48 buckets and block edges
 MAX_NEW = 6
@@ -40,16 +44,30 @@ def _prompts(cfg, lengths, seed=0):
             for n in lengths]
 
 
-def _oracle(cfg, params, prompt, max_new=MAX_NEW):
-    """Unbatched greedy reference: plain ring prefill + decode, no padding."""
+def _oracle(cfg, params, prompt, max_new=MAX_NEW, pick=np.argmax):
+    """Unbatched reference: plain ring prefill + decode, no padding; each
+    token is ``pick`` of the f32 logits on the host (greedy by default)."""
     logits, cache = prefill(params, {"tokens": jnp.asarray(prompt[None])},
                             cfg, max_new_tokens=max_new)
-    out = [int(jnp.argmax(logits[0]))]
+    out = [int(pick(np.asarray(logits[0])))]
     while len(out) < max_new:
         logits, cache = decode_step(
             params, cache, jnp.asarray([[out[-1]]], jnp.int32), cfg)
-        out.append(int(jnp.argmax(logits[0])))
+        out.append(int(pick(np.asarray(logits[0]))))
     return out
+
+
+def _sampler(req):
+    """Softmax sampling at ``req.temperature`` from ``req.seed``'s
+    generator, one draw per token."""
+    rng = np.random.default_rng(req.seed)
+
+    def pick(row):
+        z = row.astype(np.float64) / req.temperature
+        p = np.exp(z - z.max())
+        return rng.choice(len(p), p=p / p.sum())
+
+    return pick
 
 
 def _server(cfg, params, engine, **kw):
@@ -239,3 +257,110 @@ def test_ring_mode_is_the_same_api(cfg, params):
     assert ragged.status == "rejected" and "uniform" in ragged.reason
     for h in handles:
         assert h.tokens == _oracle(cfg, params, h.request.prompt)
+
+
+# ------------------------------------------ greedy ids chained on the device
+def _decode_counts(reg):
+    return {k: reg.counter(f"server.decode.{k}").value
+            for k in ("token_uploads", "host_rows", "table_uploads")}
+
+
+@pytest.mark.parametrize("kv,lengths", [("paged", LENGTHS),
+                                        ("ring", (16, 16, 16))])
+def test_device_chained_tokens_match_the_host_argmax_oracle(cfg, params, kv,
+                                                            lengths):
+    """Each tick's greedy ids feed the next tick on the device; the host
+    uploads a token array only after an admission.  The streams equal the
+    host-argmax oracle's, and ticks that alternate device-chained and
+    uploaded tokens share one trace of every step."""
+    reg = Registry()
+    eng = Engine(registry=reg)
+    prompts = _prompts(cfg, lengths)
+    with eng.activate():
+        server = _server(cfg, params, eng, kv=kv)
+        for p in prompts:
+            server.submit(Request(p, max_new_tokens=MAX_NEW))
+        server.drain()
+        warm, ticks0 = eng.stats.traces, server.decode_ticks
+        uploads0 = _decode_counts(reg)["token_uploads"]
+        for p in reversed(prompts):
+            server.submit(Request(p, max_new_tokens=MAX_NEW))
+        server.drain()
+    counts = _decode_counts(reg)
+    assert eng.stats.traces == warm
+    uploads = counts["token_uploads"] - uploads0
+    assert 0 < uploads < server.decode_ticks - ticks0, \
+        "the second wave must mix device-chained and uploaded tokens"
+    assert counts["host_rows"] == 0, "greedy rows never send logits home"
+    for h in server.handles:
+        assert h.tokens == _oracle(cfg, params, h.request.prompt)
+
+
+def test_sampled_rows_keep_their_host_stream_beside_greedy_rows(cfg, params):
+    """Rows with a temperature sample every token on the host from their
+    own logits and seeded generator; greedy rows beside them stay the
+    greedy oracle's."""
+    prompts = _prompts(cfg, (7, 16, 33, 12))
+    hot = [Request(prompts[i], max_new_tokens=MAX_NEW, seed=10 + i,
+                   temperature=0.8) for i in (0, 2)]
+    cold = [Request(prompts[i], max_new_tokens=MAX_NEW) for i in (1, 3)]
+    reg = Registry()
+    eng = Engine(registry=reg)
+    with eng.activate():
+        server = _server(cfg, params, eng)
+        hs = [server.submit(r) for r in (hot[0], cold[0], hot[1], cold[1])]
+        server.drain()
+    for h in hs:
+        r = h.request
+        pick = _sampler(r) if r.temperature > 0 else np.argmax
+        assert h.tokens == _oracle(cfg, params, r.prompt, pick=pick)
+    assert [h.tokens for h in hs[::2]] != \
+        [_oracle(cfg, params, r.prompt) for r in hot], "temperature samples"
+    assert _decode_counts(reg)["host_rows"] == 2 * (MAX_NEW - 1), \
+        "only the sampled rows' decode tokens read logits on the host"
+
+
+def test_greedy_drain_uploads_tokens_only_after_admissions(cfg, params):
+    """Eight slots freed and refilled at staggered ticks: no logits leave
+    the device, a token array goes up only on a tick that follows an
+    admission, and the block table only when it changed."""
+    reg = Registry()
+    eng = Engine(registry=reg)
+    prompts = _prompts(cfg, (7, 16, 33, 12, 5, 20, 9, 40, 14, 3, 30, 16))
+    with eng.activate():
+        server = _server(cfg, params, eng, slots=8, block_size=16,
+                         max_seq_len=48 + 12)
+        for i, p in enumerate(prompts):
+            server.submit(Request(p, max_new_tokens=2 + i % 8))
+        after_admission = 0
+        while server.queued or any(server.active):
+            started = sum(bool(h.tokens) for h in server.handles)
+            ticks = server.decode_ticks
+            server.poll()
+            admitted = sum(bool(h.tokens) for h in server.handles) > started
+            after_admission += admitted and server.decode_ticks > ticks
+    counts = _decode_counts(reg)
+    assert counts["host_rows"] == 0
+    assert counts["token_uploads"] == after_admission
+    assert after_admission < server.decode_ticks
+    assert 0 < counts["table_uploads"] < server.decode_ticks
+    for h in server.handles:
+        assert h.tokens == _oracle(cfg, params, h.request.prompt,
+                                   h.request.max_new_tokens)
+
+
+def test_a_fault_in_the_device_argmax_changes_the_stream(cfg, params):
+    """The device's argmax is where a greedy token is produced: one row's
+    id shifted there must show in the served stream, from that tick on."""
+    prompt = _prompts(cfg, (16,))[0]
+    ref = _oracle(cfg, params, prompt)
+    eng = Engine(registry=Registry())
+    with eng.activate():
+        server = _server(cfg, params, eng)
+        ids = server._ids
+        shift = jnp.zeros((server.slots, 1), jnp.int32).at[0, 0].set(1)
+        server._ids = lambda logits: (ids(logits) + shift) % cfg.vocab_size
+        h = server.submit(Request(prompt, max_new_tokens=MAX_NEW))
+        server.drain()
+    assert h.slot == 0 and h.tokens[0] == ref[0]
+    assert h.tokens[1] == (ref[1] + 1) % cfg.vocab_size
