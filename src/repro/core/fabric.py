@@ -203,10 +203,11 @@ def _exact_pallas(qa, qw, spec, key):
 
 
 def _sim_correction(qa, qw, spec):
-    u_a = to_offset_binary(qa, spec.bits_a)
-    u_w = to_offset_binary(qw, spec.bits_w)
-    return u_a, u_w, signed_product_correction(u_a, u_w, spec.bits_a,
-                                               spec.bits_w)
+    with jax.named_scope("fabric.quantize"):
+        u_a = to_offset_binary(qa, spec.bits_a)
+        u_w = to_offset_binary(qw, spec.bits_w)
+        return u_a, u_w, signed_product_correction(u_a, u_w, spec.bits_a,
+                                                   spec.bits_w)
 
 
 @register_engine("sim", "jnp", False)
@@ -255,8 +256,9 @@ def _sim_pallas_noisy(qa, qw, spec, key):
 def _fabric_matmul_jit(x, w, spec: FabricSpec, key, geom):
     del geom  # cache-key only: retrace when tuned kernel geometry changes
     engine = resolve_engine(spec)
-    qx = quantize(x, spec.bits_a, axis=None)
-    qw = quantize(w, spec.bits_w, axis=0)  # per-column (output channel)
+    with jax.named_scope("fabric.quantize"):
+        qx = quantize(x, spec.bits_a, axis=None)
+        qw = quantize(w, spec.bits_w, axis=0)  # per-column (output channel)
     acc = engine(qx.q, qw.q, spec, key)
     return acc.astype(jnp.float32) * qx.scale * qw.scale.reshape(
         (1,) * (acc.ndim - 1) + (-1,))
@@ -273,6 +275,13 @@ def fabric_matmul(x, w, spec: FabricSpec = FabricSpec(), *, key=None):
     specs under an unchanged tuning state share one compiled executable, and
     a re-tune (or a ``REPRO_TUNE_*`` pin change) busts the cache instead of
     silently reusing stale tile geometry.
+
+    The stages run under named scopes, so a profiler trace names the device
+    ops of each: ``fabric.quantize`` (the codes, and on the sim path their
+    offset binary and the signed-product correction), then ``fabric.planes``
+    and ``fabric.mac`` (the ``bitplane_mac`` engine's bit-plane explode and
+    padding, and its kernel).  The rescale takes no scope: XLA fuses it into
+    the op that reads the projection.
     """
     from repro.kernels import autotune
 

@@ -18,6 +18,7 @@ Explicit ``bm``/``bn``/``bk`` arguments always win over the tuner.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -46,26 +47,40 @@ def _resolve_geometry(m: int, k: int, n: int, bits_a: int, bits_w: int,
     return geom
 
 
+def _padded_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk):
+    """Both operands exploded into bit-planes ([PA, M, K] with the batch
+    dims flattened into M, and [PW, K, N]) and zero-padded to the block
+    grid, under the ``fabric.planes`` scope."""
+    with jax.named_scope("fabric.planes"):
+        k = u_a.shape[-1]
+        a_planes = to_bitplanes(u_a.reshape(-1, k), bits_a)
+        w_planes = to_bitplanes(u_w, bits_w)
+        m, n = a_planes.shape[1], w_planes.shape[2]
+        pm, pk, pn = (-m) % bm, (-k) % bk, (-n) % bn
+        if pm or pk:
+            a_planes = jnp.pad(a_planes, ((0, 0), (0, pm), (0, pk)))
+        if pk or pn:
+            w_planes = jnp.pad(w_planes, ((0, 0), (0, pk), (0, pn)))
+    return a_planes, w_planes
+
+
+def _unpad(out, u_a, u_w):
+    """The kernel's padded int32[M', N'] result cut to ``u_a @ u_w``'s
+    shape."""
+    batch = u_a.shape[:-1]
+    n = u_w.shape[-1]
+    return out[:math.prod(batch), :n].reshape(*batch, n)
+
+
 @functools.partial(jax.jit, static_argnames=("bits_a", "bits_w", "rows",
                                              "bm", "bn", "bk", "interpret"))
 def _bitplane_mac_jit(u_a, u_w, thr, *, bits_a, bits_w, rows, bm, bn, bk,
                       interpret):
-    batch = u_a.shape[:-1]
-    m = 1
-    for b in batch:
-        m *= b
-    k = u_a.shape[-1]
-    n = u_w.shape[-1]
-    a_planes = to_bitplanes(u_a.reshape(m, k), bits_a)  # [PA, M, K]
-    w_planes = to_bitplanes(u_w, bits_w)                # [PW, K, N]
-    pm, pk, pn = (-m) % bm, (-k) % bk, (-n) % bn
-    if pm or pk:
-        a_planes = jnp.pad(a_planes, ((0, 0), (0, pm), (0, pk)))
-    if pk or pn:
-        w_planes = jnp.pad(w_planes, ((0, 0), (0, pk), (0, pn)))
-    out = bitplane_mac_raw(a_planes, w_planes, thr, rows=rows, bm=bm, bn=bn,
-                           bk=bk, interpret=interpret)
-    return out[:m, :n].reshape(*batch, n)
+    a_planes, w_planes = _padded_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk)
+    with jax.named_scope("fabric.mac"):
+        out = bitplane_mac_raw(a_planes, w_planes, thr, rows=rows, bm=bm,
+                               bn=bn, bk=bk, interpret=interpret)
+        return _unpad(out, u_a, u_w)
 
 
 def bitplane_mac(u_a, u_w, thr=None, *, bits_a: int = 8, bits_w: int = 8,
@@ -82,12 +97,9 @@ def bitplane_mac(u_a, u_w, thr=None, *, bits_a: int = 8, bits_w: int = 8,
     Returns int32[..., N] == u_a @ u_w (noise-free decode is exact).
     """
     interpret = resolve_interpret(interpret)
-    batch = u_a.shape[:-1]
-    m = 1
-    for b in batch:
-        m *= b
-    geom = _resolve_geometry(m, u_a.shape[-1], u_w.shape[-1], bits_a, bits_w,
-                             bm, bn, bk, interpret)
+    geom = _resolve_geometry(math.prod(u_a.shape[:-1]), u_a.shape[-1],
+                             u_w.shape[-1], bits_a, bits_w, bm, bn, bk,
+                             interpret)
     if thr is None:
         thr = core_thresholds(rows, mode="physics")
     return _bitplane_mac_jit(u_a, u_w, thr, bits_a=bits_a, bits_w=bits_w,
@@ -123,25 +135,14 @@ def _key_words(key):
 def _bitplane_mac_noisy_jit(u_a, u_w, thr, key, *, bits_a, bits_w, rows,
                             mismatch_sigma, comparator_offset_sigma, bm, bn,
                             bk, interpret):
-    batch = u_a.shape[:-1]
-    m = 1
-    for b in batch:
-        m *= b
-    k = u_a.shape[-1]
-    n = u_w.shape[-1]
-    a_planes = to_bitplanes(u_a.reshape(m, k), bits_a)
-    w_planes = to_bitplanes(u_w, bits_w)
-    pm, pk, pn = (-m) % bm, (-k) % bk, (-n) % bn
-    if pm or pk:
-        a_planes = jnp.pad(a_planes, ((0, 0), (0, pm), (0, pk)))
-    if pk or pn:
-        w_planes = jnp.pad(w_planes, ((0, 0), (0, pk), (0, pn)))
-    out = bitplane_mac_noisy_raw(
-        a_planes, w_planes, thr, _key_words(key), rows=rows, bm=bm, bn=bn,
-        bk=bk, mismatch_sigma=mismatch_sigma,
-        comparator_offset_sigma=comparator_offset_sigma,
-        valid_groups=-(-k // rows), interpret=interpret)
-    return out[:m, :n].reshape(*batch, n)
+    a_planes, w_planes = _padded_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk)
+    with jax.named_scope("fabric.mac"):
+        out = bitplane_mac_noisy_raw(
+            a_planes, w_planes, thr, _key_words(key), rows=rows, bm=bm,
+            bn=bn, bk=bk, mismatch_sigma=mismatch_sigma,
+            comparator_offset_sigma=comparator_offset_sigma,
+            valid_groups=-(-u_a.shape[-1] // rows), interpret=interpret)
+        return _unpad(out, u_a, u_w)
 
 
 def bitplane_mac_noisy(u_a, u_w, key, thr=None, *, bits_a: int = 8,
@@ -163,12 +164,9 @@ def bitplane_mac_noisy(u_a, u_w, key, thr=None, *, bits_a: int = 8,
     interpret = resolve_interpret(interpret)
     if thr is None:
         thr = core_thresholds(rows, mode="physics")
-    batch = u_a.shape[:-1]
-    m = 1
-    for b in batch:
-        m *= b
-    geom = _resolve_geometry(m, u_a.shape[-1], u_w.shape[-1], bits_a, bits_w,
-                             bm, bn, bk, interpret)
+    geom = _resolve_geometry(math.prod(u_a.shape[:-1]), u_a.shape[-1],
+                             u_w.shape[-1], bits_a, bits_w, bm, bn, bk,
+                             interpret)
     return _bitplane_mac_noisy_jit(
         u_a, u_w, thr, key, bits_a=bits_a, bits_w=bits_w, rows=rows,
         mismatch_sigma=mismatch_sigma,

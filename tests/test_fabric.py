@@ -347,3 +347,29 @@ def test_equal_specs_share_one_jit_entry():
     fabric_matmul(x, w, FabricSpec(bits_a=4, bits_w=5, mode="sim",
                                    backend="jnp"))
     assert fabric_matmul._cache_size() == n_before + 1
+
+
+# ----------------------------------------------------------- named scopes
+@pytest.mark.parametrize("backend,scopes", [
+    ("jnp", ("fabric.quantize",)),
+    ("pallas", ("fabric.quantize", "fabric.planes", "fabric.mac")),
+])
+def test_sim_stages_are_scoped_and_stay_exact(backend, scopes):
+    """The fabric's stages carry named scopes into the lowered module (a
+    profiler trace names device ops by them); the scopes change neither the
+    noise-free sim's numbers, which equal the exact digital product bit for
+    bit, nor the jit cache."""
+    import re
+
+    x, w = _xw(m=4, k=32, n=8, seed=11)
+    spec = FabricSpec(mode="sim", backend=backend)
+    y = fabric_matmul(x, w, spec)
+    exact = fabric_matmul(x, w, FabricSpec(mode="exact"))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(exact))
+    n = fabric_matmul._cache_size()
+    fabric_matmul(x, w, spec)
+    assert fabric_matmul._cache_size() == n
+    text = jax.jit(lambda a, b: fabric_matmul(a, b, spec)).lower(
+        x, w).as_text(debug_info=True)
+    found = set(re.findall(r"fabric\.(?:quantize|planes|mac)", text))
+    assert found == set(scopes)
