@@ -42,8 +42,9 @@ MAX_SEQ_LEN = 1024  # 8 slots x 1024 tokens of KV: ~300 MB at full width
 BLOCK_SIZE = 16
 BUCKETS = (32, 128)
 # A sim projection runs 64 plane-pair contractions; at full width a sim
-# forward costs about 0.53 s of device time per layer on one TPU v5e (2.13 s
-# for 4 layers, at most 128 rows), so the fabric phases serve the first
+# forward costs, on one TPU v5e, about 36 ms of device time per layer for an
+# 8-row decode tick (146 ms for 4 layers) and about 0.53 s per layer for a
+# 128-row prefill (2.13 s for 4), so the fabric phases serve the first
 # FABRIC_LAYERS layers of the same full-width model.
 FABRIC_LAYERS = 4
 # Tolerances tests/test_paged_attn.py pins for the kernel against the oracle.

@@ -271,7 +271,9 @@ _MEASURE = {
 }
 
 # Default candidate spaces (small on purpose: tune() is explicit, and a
-# committed cache makes CI trial-free).
+# committed cache makes CI trial-free).  ``bitplane_mac``'s ``bm`` counts
+# rows of its plane-folded axis (activation planes x rows, M padded to 8),
+# and the wrapper caps it at that axis's length.
 SPACES: Dict[str, List[Dict[str, int]]] = {
     "bitplane_mac": [
         {"bm": bm, "bn": bn, "bk": bk}

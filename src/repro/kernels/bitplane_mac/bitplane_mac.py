@@ -2,10 +2,11 @@
 
 Generalizes ``repro.kernels.rbl_decode`` from one bit-plane pair to all
 ``bits_a x bits_w`` pairs: per output tile the kernel sweeps plane pairs and
-K-blocks, and for every (pair, K-block) it runs the paper's whole evaluation
-pipeline — per-8-row-group binary MAC counts, charge-sharing RBL voltage,
-comparator thermometer decode, and the ``2^(p+q)``-weighted digital
-shift-accumulate — without leaving VMEM:
+K-blocks (the activation planes ride the row axis, see below), and for every
+(pair, K-block) it runs the paper's whole evaluation pipeline — per-8-row-
+group binary MAC counts, charge-sharing RBL voltage, comparator thermometer
+decode, and the ``2^(p+q)``-weighted digital shift-accumulate — without
+leaving VMEM:
 
   out[m, n] = sum_{p,q} 2^{p+q} sum_g decode( V( sum_r a[p, m, g*rows+r]
                                                    * w[q, g*rows+r, n] ) )
@@ -16,35 +17,43 @@ is bit-identical to the plane-batched jnp engine AND the seed per-plane loop
 becomes ONE kernel launch with a single int32 accumulator per tile.
 
 Implementation notes (TPU adaptation):
-  * grid (M/bm, N/bn, PP, K/bk) with the plane-pair axis PP = bits_a * bits_w
-    third and K innermost; both are "arbitrary" (they carry the accumulator),
-    M/N tiles are parallel.
-  * the index maps recover (p, q) from the flat pair index by div/mod, so the
-    activation planes tensor [PA, M, K] and the weight planes tensor
-    [PW, K, N] are streamed block-by-block — VMEM never holds more than one
-    (bm, bk) + (bk, bn) plane slice.
+  * the row axis carries (activation plane, row) pairs: the wrapper folds
+    the activation planes [PA, M, K] into rows [PA*M', K] (M' = M padded to
+    a multiple of 8), so a decode tick's 8 rows fill a 64-row tile with
+    real bits instead of padding one 128-row tile per activation plane.
+  * grid (R/bm, N/bn, PW, K/bk) over R folded rows, with the weight-plane
+    axis third and K innermost; both are "arbitrary" (they carry the
+    accumulator), row/N tiles are parallel.  Each (bk, bn) weight-plane
+    block is read once per row tile, for every activation plane at once;
+    VMEM never holds more than one (bm, bk) + (bk, bn) plane slice.
   * group MACs are one (bm, rows) x (rows, bn) bf16 dot per row-group,
     unrolled over the ``bk // rows`` groups of a K-block and stacked into
     (groups, bm, bn) counts (Mosaic lowers no in-kernel reshape that splits
     the lane axis into groups); V(k) is the fitted two-regime physics on
     the VPU; the comparator bank is ``rows`` broadcast compares.
-  * the plane weight 2^(p+q) is computed from ``pl.program_id`` on the fly
-    (shift of an int32 one), and accumulation is int32 — float32 would lose
-    bit-exactness beyond 2^24 for deep-K 8-bit operands.
+  * the plane weight 2^(p+q) is split: each step adds its 2^q-weighted
+    decode (q from ``pl.program_id``), and the tile's flush shifts each row
+    by its p, found from the row's global index, so the inner loop costs
+    what the unfolded kernel's did.  Accumulation is int32 (float32 would
+    lose bit-exactness beyond 2^24 for deep-K 8-bit operands); the kernel
+    writes each row's partial sum, at most 2^(PA-1) * (2^PW - 1) * K, and
+    the wrapper adds the PA row blocks.
   * thresholds arrive as a (1, rows) block so corner-re-tuned references
     (paper §IV-C) stay a data, not code, change.
 
-The NOISY sibling (:func:`bitplane_mac_noisy_raw`) keeps the identical grid
-and accumulator but runs the :class:`~repro.core.fabric.NoiseSpec`
-Monte-Carlo INSIDE the kernel: per grid step it builds a PRNG stream seeded
-from (fabric key words, flattened grid-step index) — the Mosaic hardware PRNG
-when compiled, the counter-hash fallback in interpret mode
-(``kernels.common.make_normal_sampler``) — then applies Gaussian device
-mismatch to the effective counts ahead of the RBL voltage map and comparator
-offset to the decode references, so all 64 plane pairs x K-groups x decode x
-accumulate stay ONE ``pallas_call`` for noisy specs too.  The key words ride
-in via scalar prefetch (``pltpu.PrefetchScalarGridSpec``).  Noise draws are
-necessarily a different bit stream than the keyed jnp engine's threefry, so
+The NOISY sibling (:func:`bitplane_mac_noisy_raw`) keeps the unfolded
+layout — activation planes [PA, M, K] on a (M/bm, N/bn, PA*PW, K/bk) grid,
+the pair axis recovering (p, q) by div/mod — and runs the
+:class:`~repro.core.fabric.NoiseSpec` Monte-Carlo INSIDE the kernel: per
+grid step it builds a PRNG stream seeded from (fabric key words, flattened
+grid-step index) — the Mosaic hardware PRNG when compiled, the counter-hash
+fallback in interpret mode (``kernels.common.make_normal_sampler``) — then
+applies Gaussian device mismatch to the effective counts ahead of the RBL
+voltage map and comparator offset to the decode references, so all 64 plane
+pairs x K-groups x decode x accumulate stay ONE ``pallas_call`` for noisy
+specs too.  The key words ride in via scalar prefetch
+(``pltpu.PrefetchScalarGridSpec``).  Noise draws are necessarily a different
+bit stream than the keyed jnp engine's threefry, so
 parity with that oracle is statistical (moments/quantiles), never bitwise.
 """
 from __future__ import annotations
@@ -61,14 +70,14 @@ from repro.kernels.common import (decode_counts, decode_counts_noisy,
                                   make_normal_sampler)
 
 
-def _group_counts(a_ref, b_ref, rows: int):
+def _group_counts(a, b, rows: int):
     """counts[g, m, n] = sum_r a[m, g*rows + r] * b[g*rows + r, n]: the
     binary MAC of every row-group of the block, exact in f32 (at most
     ``rows``).  One (bm, rows) x (rows, bn) bf16 dot per group (bits are
     {0,1}, so bf16 is exact); the weight block goes through f32 so that its
     row-group slices fall on whole (8, 128) tiles."""
-    a = a_ref[0].astype(jnp.float32).astype(jnp.bfloat16)
-    b = b_ref[0].astype(jnp.float32)
+    a = a.astype(jnp.float32).astype(jnp.bfloat16)
+    b = b.astype(jnp.float32)
     return jnp.stack([
         jax.lax.dot_general(
             a[:, lo:lo + rows], b[lo:lo + rows, :].astype(jnp.bfloat16),
@@ -76,63 +85,73 @@ def _group_counts(a_ref, b_ref, rows: int):
         for lo in range(0, a.shape[1], rows)])
 
 
-def _make_kernel(rows: int, bits_w: int):
+def _make_kernel(rows: int, m_pad: int, bits_a: int):
     def kernel(a_ref, b_ref, thr_ref, o_ref, acc_ref):
-        pp = pl.program_id(2)
+        i = pl.program_id(0)
+        q = pl.program_id(2)
         kk = pl.program_id(3)
 
-        @pl.when((pp == 0) & (kk == 0))
+        @pl.when((q == 0) & (kk == 0))
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        dec = decode_counts(_group_counts(a_ref, b_ref, rows), thr_ref[...],
-                            rows)
-        # digital shift-accumulate: weight = 2^(p+q), pair index pp = p*PW + q
-        shift = pp // bits_w + pp % bits_w
-        weight = jax.lax.shift_left(jnp.int32(1), shift)
+        dec = decode_counts(_group_counts(a_ref[...], b_ref[0], rows),
+                            thr_ref[...], rows)
+        # digital shift-accumulate, weight-plane part 2^q ...
+        weight = jax.lax.shift_left(jnp.int32(1), q)
         acc_ref[...] += weight * jnp.sum(dec, axis=0).astype(jnp.int32)
 
-        @pl.when((pp == pl.num_programs(2) - 1)
+        @pl.when((q == pl.num_programs(2) - 1)
                  & (kk == pl.num_programs(3) - 1))
         def _flush():
-            o_ref[...] = acc_ref[...]
+            # ... and activation-plane part 2^p once per tile: folded row r
+            # holds plane p = r // m_pad, found by compares (Mosaic has no
+            # vector integer division).
+            r = i * acc_ref.shape[0] + jax.lax.broadcasted_iota(
+                jnp.int32, acc_ref.shape, 0)
+            p = sum((r >= t * m_pad).astype(jnp.int32)
+                    for t in range(1, bits_a))
+            o_ref[...] = jax.lax.shift_left(acc_ref[...], p)
 
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "bm", "bn", "bk",
-                                             "interpret"))
-def bitplane_mac_raw(a_planes, w_planes, thresholds, *, rows: int = C.ROWS,
-                     bm: int = 128, bn: int = 128, bk: int = 256,
-                     interpret: bool = False):
-    """Fused full-pyramid decode MAC.
+@functools.partial(jax.jit, static_argnames=("rows", "m_pad", "bits_a",
+                                             "bm", "bn", "bk", "interpret"))
+def bitplane_mac_raw(a_rows, w_planes, thresholds, *, m_pad: int,
+                     bits_a: int, rows: int = C.ROWS, bm: int = 128,
+                     bn: int = 128, bk: int = 256, interpret: bool = False):
+    """Fused full-pyramid decode MAC over plane-folded activation rows.
 
-    a_planes: int8[PA, M, K] in {0,1} (activation bit-planes, LSB first);
-    w_planes: int8[PW, K, N] in {0,1}; thresholds: float32[rows] descending.
-    M, N, K must be divisible by (bm, bn, bk) and bk by rows (ops.py pads).
-    Returns int32[M, N] = sum_{p,q} 2^(p+q) * sum_g decoded_count[p, q, g].
+    a_rows: int8[R, K] in {0,1}: the activation bit-planes [PA, m_pad, K]
+    (LSB first) flattened to rows, plane p in rows [p*m_pad, (p+1)*m_pad),
+    then zero rows up to R (``bits_a`` = PA); w_planes: int8[PW, K, N] in
+    {0,1}; thresholds: float32[rows] descending.  R, N, K must be divisible
+    by (bm, bn, bk) and bk by rows (ops.py pads).  Returns int32[R, N]: row
+    p*m_pad + m holds sum_q 2^(p+q) * sum_g decoded_count[p, q, g] of row
+    m; summing the PA row blocks gives the product.
     """
-    pa, m, k = a_planes.shape
+    r, k = a_rows.shape
     pw, k2, n = w_planes.shape
-    assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0
-    assert bk % rows == 0
-    grid = (m // bm, n // bn, pa * pw, k // bk)
+    assert k == k2 and r % bm == 0 and n % bn == 0 and k % bk == 0
+    assert bk % rows == 0 and r >= bits_a * m_pad
+    grid = (r // bm, n // bn, pw, k // bk)
     return pl.pallas_call(
-        _make_kernel(rows, pw),
+        _make_kernel(rows, m_pad, bits_a),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda i, j, pp, kk: (pp // pw, i, kk)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, pp, kk: (pp % pw, kk, j)),
-            pl.BlockSpec((1, rows), lambda i, j, pp, kk: (0, 0)),
+            pl.BlockSpec((bm, bk), lambda i, j, q, kk: (i, kk)),
+            pl.BlockSpec((1, bk, bn), lambda i, j, q, kk: (q, kk, j)),
+            pl.BlockSpec((1, rows), lambda i, j, q, kk: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, pp, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, q, kk: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((r, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
-    )(a_planes.astype(jnp.int8), w_planes.astype(jnp.int8),
+    )(a_rows.astype(jnp.int8), w_planes.astype(jnp.int8),
       jnp.asarray(thresholds, jnp.float32).reshape(1, rows))
 
 
@@ -159,8 +178,8 @@ def _make_noisy_kernel(rows: int, bk: int, bits_w: int, mismatch_sigma,
             (seed_ref[0], seed_ref[1], step), hw_prng=hw_prng)
 
         dec = decode_counts_noisy(
-            _group_counts(a_ref, b_ref, rows), thr_ref[...], rows, normal,
-            mismatch_sigma=mismatch_sigma,
+            _group_counts(a_ref[0], b_ref[0], rows), thr_ref[...], rows,
+            normal, mismatch_sigma=mismatch_sigma,
             comparator_offset_sigma=comparator_sigma)
         # Padded K-groups (beyond the operand's real K) must not decode:
         # unlike the noise-free kernel — where decode(0) == 0 makes padding
@@ -192,9 +211,12 @@ def bitplane_mac_noisy_raw(a_planes, w_planes, thresholds, seed, *,
                            interpret: bool = False):
     """Fused full-pyramid decode MAC with in-kernel NoiseSpec Monte-Carlo.
 
-    Same operand contract as :func:`bitplane_mac_raw`, plus ``seed`` —
-    int32[2] PRNG key words (scalar-prefetched) — and the static noise
-    sigmas.  ``valid_groups`` is the number of REAL row-groups (pre-padding,
+    a_planes: int8[PA, M, K] in {0,1} (activation bit-planes, LSB first,
+    not folded: the noise stream is keyed by the flattened index of the
+    (M/bm, N/bn, PA*PW, K/bk) grid); w_planes, thresholds as in
+    :func:`bitplane_mac_raw`; M, N, K divisible by (bm, bn, bk).  ``seed``
+    is int32[2] PRNG key words (scalar-prefetched); the noise sigmas are
+    static.  ``valid_groups`` is the number of REAL row-groups (pre-padding,
     ``ceil(K_orig / rows)``; defaults to all): groups past it are K-padding
     and their decodes are masked, because comparator offset can flip a
     zero-count group's decode — mismatch alone is padding-safe (stddev

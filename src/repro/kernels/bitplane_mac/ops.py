@@ -7,6 +7,17 @@ result.  Zero padding is safe end-to-end: a zero bit contributes count 0 and
 the decode maps 0 -> 0 (see the noisy-raw docstring for the noise argument),
 so padded groups add nothing to the accumulator.
 
+The noise-free entry folds the activation planes into the kernel's row
+axis: M is padded to a multiple of 8, the planes [PA, M', K] become rows
+[PA*M', K], and ``bm`` counts rows of that folded axis (the tile is
+``min(bm, PA*M')``, so an 8-row 8-bit decode tick is one 64-row tile and a
+128-row prefill eight 128-row tiles).  The kernel returns one 2^p-weighted
+partial sum per folded row; the wrapper adds the PA row blocks under the
+``fabric.mac`` scope.  Trace-time counters ``fabric.mac.real_rows`` (PA*M)
+and ``fabric.mac.tile_rows`` (folded rows the grid launches) give the row
+fill of each traced shape.  The noisy entry keeps the unfolded [PA, M, K]
+layout, whose grid keys its noise stream.
+
 The wrappers are deliberately PLAIN functions in front of inner jits: tile
 geometry defaults to the autotuner's cached winner for the call's shape
 bucket (``repro.kernels.autotune``), and that resolution must happen at call
@@ -30,6 +41,7 @@ from repro.kernels import autotune
 from repro.kernels.bitplane_mac.bitplane_mac import (bitplane_mac_noisy_raw,
                                                      bitplane_mac_raw)
 from repro.kernels.compat import resolve_interpret
+from repro.telemetry import get_registry
 
 
 def _resolve_geometry(m: int, k: int, n: int, bits_a: int, bits_w: int,
@@ -47,20 +59,50 @@ def _resolve_geometry(m: int, k: int, n: int, bits_a: int, bits_w: int,
     return geom
 
 
+def _weight_planes(u_w, bits_w, bn, bk):
+    """Weight bit-planes [PW, K, N] zero-padded to the block grid."""
+    w_planes = to_bitplanes(u_w, bits_w)
+    k, n = w_planes.shape[1:]
+    pk, pn = (-k) % bk, (-n) % bn
+    if pk or pn:
+        w_planes = jnp.pad(w_planes, ((0, 0), (0, pk), (0, pn)))
+    return w_planes
+
+
 def _padded_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk):
-    """Both operands exploded into bit-planes ([PA, M, K] with the batch
-    dims flattened into M, and [PW, K, N]) and zero-padded to the block
-    grid, under the ``fabric.planes`` scope."""
+    """Both operands exploded into bit-planes and zero-padded to the block
+    grid, under the ``fabric.planes`` scope: the activation planes folded
+    into rows [PA * m_pad, K] (batch dims flattened into M, M padded to
+    ``m_pad``, a multiple of 8; plane p in rows [p*m_pad, (p+1)*m_pad)),
+    then zero rows up to a multiple of the row tile, ``min(bm, PA *
+    m_pad)``; and [PW, K, N].  Returns (rows, weight planes, m_pad, tile)."""
     with jax.named_scope("fabric.planes"):
         k = u_a.shape[-1]
         a_planes = to_bitplanes(u_a.reshape(-1, k), bits_a)
-        w_planes = to_bitplanes(u_w, bits_w)
-        m, n = a_planes.shape[1], w_planes.shape[2]
-        pm, pk, pn = (-m) % bm, (-k) % bk, (-n) % bn
+        m = a_planes.shape[1]
+        m_pad = m + (-m) % 8
+        a_planes = jnp.pad(a_planes, ((0, 0), (0, m_pad - m),
+                                      (0, (-k) % bk)))
+        a_rows = a_planes.reshape(bits_a * m_pad, a_planes.shape[2])
+        bm = min(bm, bits_a * m_pad)
+        pr = (-a_rows.shape[0]) % bm
+        if pr:
+            a_rows = jnp.pad(a_rows, ((0, pr), (0, 0)))
+        w_planes = _weight_planes(u_w, bits_w, bn, bk)
+    return a_rows, w_planes, m_pad, bm
+
+
+def _stacked_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk):
+    """The noisy kernel's operands, under the ``fabric.planes`` scope:
+    activation planes [PA, M, K] (batch dims flattened into M) and
+    [PW, K, N], each zero-padded to the block grid."""
+    with jax.named_scope("fabric.planes"):
+        k = u_a.shape[-1]
+        a_planes = to_bitplanes(u_a.reshape(-1, k), bits_a)
+        pm, pk = (-a_planes.shape[1]) % bm, (-k) % bk
         if pm or pk:
             a_planes = jnp.pad(a_planes, ((0, 0), (0, pm), (0, pk)))
-        if pk or pn:
-            w_planes = jnp.pad(w_planes, ((0, 0), (0, pk), (0, pn)))
+        w_planes = _weight_planes(u_w, bits_w, bn, bk)
     return a_planes, w_planes
 
 
@@ -76,11 +118,23 @@ def _unpad(out, u_a, u_w):
                                              "bm", "bn", "bk", "interpret"))
 def _bitplane_mac_jit(u_a, u_w, thr, *, bits_a, bits_w, rows, bm, bn, bk,
                       interpret):
-    a_planes, w_planes = _padded_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk)
+    a_rows, w_planes, m_pad, bm = _padded_planes(u_a, u_w, bits_a, bits_w,
+                                                 bm, bn, bk)
+    batch, n = u_a.shape[:-1], u_w.shape[-1]
+    m = math.prod(batch)
+    # Trace-time row fill of the folded tile: real (plane, row) pairs over
+    # the rows the grid launches.  Recorded once per traced shape.
+    reg = get_registry()
+    reg.counter("fabric.mac.real_rows").inc(bits_a * m)
+    reg.counter("fabric.mac.tile_rows").inc(a_rows.shape[0])
     with jax.named_scope("fabric.mac"):
-        out = bitplane_mac_raw(a_planes, w_planes, thr, rows=rows, bm=bm,
-                               bn=bn, bk=bk, interpret=interpret)
-        return _unpad(out, u_a, u_w)
+        out = bitplane_mac_raw(a_rows, w_planes, thr, m_pad=m_pad,
+                               bits_a=bits_a, rows=rows, bm=bm, bn=bn,
+                               bk=bk, interpret=interpret)
+        # each activation plane's row block holds its 2^p-weighted partial
+        # sums; their sum is the product
+        parts = out[:bits_a * m_pad, :n].reshape(bits_a, m_pad, n)[:, :m]
+        return jnp.sum(parts, axis=0, dtype=jnp.int32).reshape(*batch, n)
 
 
 def bitplane_mac(u_a, u_w, thr=None, *, bits_a: int = 8, bits_w: int = 8,
@@ -135,7 +189,8 @@ def _key_words(key):
 def _bitplane_mac_noisy_jit(u_a, u_w, thr, key, *, bits_a, bits_w, rows,
                             mismatch_sigma, comparator_offset_sigma, bm, bn,
                             bk, interpret):
-    a_planes, w_planes = _padded_planes(u_a, u_w, bits_a, bits_w, bm, bn, bk)
+    a_planes, w_planes = _stacked_planes(u_a, u_w, bits_a, bits_w, bm, bn,
+                                         bk)
     with jax.named_scope("fabric.mac"):
         out = bitplane_mac_noisy_raw(
             a_planes, w_planes, thr, _key_words(key), rows=rows, bm=bm,

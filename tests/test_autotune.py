@@ -152,3 +152,39 @@ def test_paged_attention_honors_blocks_per_step_pin(monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_PAGED_ATTN", "bps=3")
     out = paged_attention(q, kp, vp, tbl, pos, impl="pallas")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_cached_bitplane_geometries_tile_their_padded_operands():
+    """Every committed ``bitplane_mac`` geometry, with ``bm`` read as rows
+    of the plane-folded (activation plane, row) axis, tiles the operands
+    the wrapper pads for its bucket's shape, and the kernel runs on them."""
+    import re
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels.bitplane_mac.ops import _padded_planes, bitplane_mac
+
+    committed = autotune.AutotuneCache(path=autotune.default_cache_path())
+    keys = [k for k in committed.entries if k.startswith("bitplane_mac|")]
+    assert keys
+    for key in keys:
+        bucket = key.split("|")[1]
+        dims = {d: int(v) for d, v in re.findall(r"([a-z]+)(\d+)", bucket)}
+        g = committed.entries[key]["geometry"]
+        m, k, n, ba, bw = (dims[x] for x in ("m", "k", "n", "ba", "bw"))
+        ua = jnp.zeros((m, k), jnp.int32)
+        uw = jnp.zeros((k, n), jnp.int32)
+        rows, w_planes, m_pad, bm = _padded_planes(ua, uw, ba, bw, g["bm"],
+                                                   g["bn"], g["bk"])
+        assert m_pad % 8 == 0 and bm == min(g["bm"], ba * m_pad)
+        assert rows.shape[0] % bm == 0 and rows.shape[0] >= ba * m_pad
+        assert rows.shape[1] % g["bk"] == 0 == w_planes.shape[1] % g["bk"]
+        assert w_planes.shape[2] % g["bn"] == 0 and g["bk"] % 8 == 0
+    # the smallest cached cell end to end, through the committed geometry
+    rng = np.random.default_rng(3)
+    ua = jnp.asarray(rng.integers(0, 16, size=(4, 64)), jnp.int32)
+    uw = jnp.asarray(rng.integers(0, 16, size=(64, 32)), jnp.int32)
+    out = bitplane_mac(ua, uw, bits_a=4, bits_w=4, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ua) @ np.asarray(uw))

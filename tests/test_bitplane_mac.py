@@ -161,6 +161,118 @@ def test_bitplane_kernel_custom_thresholds_detune():
     assert not np.array_equal(np.asarray(out_bad), np.asarray(out_good))
 
 
+# The plane-folded row axis: every M (one row, ragged, a full decode tile,
+# prefill tiles, a ragged last tile), every plane count, ragged K and N,
+# leading batch dims and re-tuned thresholds.  Detuned references keep
+# thr[0] below V(0), so a zero count still decodes to 0 and the references'
+# K padding (to whole groups) and the kernel's (to whole blocks) agree.
+FOLD_CASES = [
+    ((1,), 8, 8, 40, 12, False),
+    ((5,), 8, 8, 40, 12, False),
+    ((8,), 8, 8, 40, 12, False),
+    ((16,), 8, 8, 24, 20, False),
+    ((24,), 8, 8, 40, 12, False),
+    ((128,), 8, 8, 16, 9, False),
+    ((130,), 8, 8, 16, 9, False),
+    ((5,), 4, 8, 37, 11, False),
+    ((130,), 4, 8, 19, 7, False),
+    ((1,), 2, 2, 33, 5, False),
+    ((24,), 2, 2, 21, 6, False),
+    ((2, 3), 4, 8, 40, 6, False),
+    ((8,), 8, 8, 40, 12, True),
+    ((2, 3), 2, 2, 27, 10, True),
+]
+
+
+def _detuned_oracle(ua, uw, thr, bits_a, bits_w, rows=8):
+    """The pyramid with explicit references: batched group counts, the
+    physics voltage, the comparator bank, the 2^(p+q) weights."""
+    from repro.kernels.common import counts_to_voltage
+
+    k = ua.shape[-1]
+    a = to_bitplanes(ua.reshape(-1, k), bits_a)
+    counts = batched_group_counts(a, to_bitplanes(uw, bits_w), rows)
+    v = counts_to_voltage(counts.astype(jnp.float32), rows)
+    dec = sum((v <= thr[i]).astype(jnp.int32) for i in range(rows))
+    out = jnp.einsum("i,imgn->mn", plane_pair_weights(bits_a, bits_w), dec)
+    return out.reshape(*ua.shape[:-1], uw.shape[-1])
+
+
+@pytest.mark.parametrize("batch,bits_a,bits_w,k,n,detune", FOLD_CASES)
+def test_folded_kernel_bitexact_vs_batched_engine_and_seed_loop(
+        batch, bits_a, bits_w, k, n, detune):
+    from repro.core.decoder import thresholds as core_thresholds
+    from repro.kernels.bitplane_mac.ops import bitplane_mac_noisy
+
+    rng = np.random.default_rng([len(batch), batch[-1], bits_a, k, n])
+    ua = jnp.asarray(rng.integers(0, 1 << bits_a, size=(*batch, k)),
+                     jnp.int32)
+    uw = jnp.asarray(rng.integers(0, 1 << bits_w, size=(k, n)), jnp.int32)
+    thr = core_thresholds(8, mode="physics")
+    if detune:
+        thr = thr.at[2:].add(0.12)
+    out = np.asarray(bitplane_mac(ua, uw, thr, bits_a=bits_a, bits_w=bits_w,
+                                  interpret=True))
+    assert out.shape == (*batch, n) and out.dtype == np.int32
+    if detune:
+        ref = np.asarray(_detuned_oracle(ua, uw, thr, bits_a, bits_w))
+        np.testing.assert_array_equal(out, ref)
+        assert not np.array_equal(out, np.asarray(ua) @ np.asarray(uw))
+        # the unfolded grid (the noisy kernel, no noise) decodes the same
+        unfolded = bitplane_mac_noisy(ua, uw, jax.random.key(0), thr,
+                                      bits_a=bits_a, bits_w=bits_w,
+                                      interpret=True)
+        np.testing.assert_array_equal(out, np.asarray(unfolded))
+        return
+    flat_a = ua.reshape(-1, k)
+    for ref in (bitplane_mac_batched_ref(flat_a, uw, bits_a=bits_a,
+                                         bits_w=bits_w),
+                bitplane_mac_ref(flat_a, uw, bits_a=bits_a, bits_w=bits_w)):
+        np.testing.assert_array_equal(out.reshape(-1, n), np.asarray(ref))
+    np.testing.assert_array_equal(out, np.asarray(ua) @ np.asarray(uw))
+
+
+def test_noisy_kernel_output_is_unchanged():
+    """The noisy kernel keeps its unfolded grid, which keys its noise
+    stream: a fixed key and shape give the output recorded before the
+    noise-free kernel's rows were folded."""
+    from repro.kernels.bitplane_mac.ops import bitplane_mac_noisy
+
+    rng = np.random.default_rng(90)
+    ua = jnp.asarray(rng.integers(0, 16, size=(3, 40)).astype(np.int32))
+    uw = jnp.asarray(rng.integers(0, 16, size=(40, 5)).astype(np.int32))
+    out = bitplane_mac_noisy(ua, uw, jax.random.key(5), bits_a=4, bits_w=4,
+                             mismatch_sigma=0.3, comparator_offset_sigma=0.05,
+                             bm=8, bn=128, bk=64, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), [
+        [2151, 2034, 1823, 1879, 2329],
+        [2302, 2440, 2560, 2264, 2401],
+        [2237, 1973, 2242, 1744, 2254]])
+
+
+@pytest.mark.parametrize("m,rows", [(8, 64), (128, 1024)])
+def test_row_fill_counters(m, rows):
+    """``fabric.mac.real_rows``/``.tile_rows`` count the folded rows once
+    per traced shape: an 8-row 8x8 call fills one 64-row tile, a 128-row
+    call eight 128-row tiles; a second call with the same shapes does not
+    retrace and adds nothing."""
+    from repro.kernels.bitplane_mac.ops import _bitplane_mac_jit
+    from repro.telemetry import get_registry
+
+    reg = get_registry()
+    real, tile = (reg.counter("fabric.mac.real_rows"),
+                  reg.counter("fabric.mac.tile_rows"))
+    ua, uw = _mk_unsigned(8, m, 16, 8, seed=m)
+    _bitplane_mac_jit.clear_cache()  # this shape is traced here first
+    r0, t0 = real.value, tile.value
+    out = bitplane_mac(ua, uw, bits_a=8, bits_w=8, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ua) @ np.asarray(uw))
+    assert (real.value - r0, tile.value - t0) == (rows, rows)
+    bitplane_mac(ua + 0, uw, bits_a=8, bits_w=8, interpret=True)
+    assert (real.value - r0, tile.value - t0) == (rows, rows)
+
+
 # ------------------------------------------------------- fabric wiring
 def test_fabric_matmul_sim_fused_kernel_matches_jnp_sim():
     from repro.core.fabric import FabricSpec

@@ -7,14 +7,16 @@ chip: nothing runs, so these tests pin only that Mosaic accepts the kernel
 and that it stays a kernel (a ``tpu_custom_call`` in the compiled module).
 
 Shapes are those of Qwen2.5-3B (d_model 2048, d_ff 11008, 16 heads over 2 KV
-heads of dim 128) serving 8 slots: a decode-tick projection for the fabric
-kernels, a paged decode over 1024-token slots, and a 512-token prefill for
-flash attention.
+heads of dim 128) serving 8 slots: decode-tick projections (and one
+128-row prefill projection) for the fabric kernels, a paged decode over
+1024-token slots, and a 512-token prefill for flash attention.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every test worker imports
 this module.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -64,10 +66,18 @@ def _compile(fn, sharding, *shapes):
     return text
 
 
-def test_bitplane_mac_8x8_compiles(one_chip, no_compile_cache):
-    _compile(lambda a, w: bitplane_mac(a, w, bits_a=8, bits_w=8,
-                                       interpret=False),
-             one_chip, ((SLOTS, D), jnp.int32), ((D, FF), jnp.int32))
+@pytest.mark.parametrize("m,k,n", [(SLOTS, D, FF), (SLOTS, FF, D),
+                                   (128, D, FF)],
+                         ids=["decode-up", "decode-down", "prefill-up"])
+def test_bitplane_mac_8x8_compiles(one_chip, no_compile_cache, m, k, n):
+    """A decode tick's projections fold into one 64-row tile, a 128-row
+    prefill's into eight 128-row tiles; either way one kernel per call."""
+    text = _compile(lambda a, w: bitplane_mac(a, w, bits_a=8, bits_w=8,
+                                              interpret=False),
+                    one_chip, ((m, k), jnp.int32), ((k, n), jnp.int32))
+    names = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom-call", text,
+                       re.M)
+    assert [x.split(".")[0] for x in names] == ["bitplane_mac_raw"]
 
 
 def test_bitplane_mac_noisy_compiles(one_chip, no_compile_cache):
