@@ -203,7 +203,7 @@ def make_requests(cfg, seed: int, n: int, max_new: int):
 
 
 def server_kw():
-    return dict(slots=SLOTS, kv="paged", block_size=BLOCK_SIZE,
+    return dict(slots=SLOTS, block_size=BLOCK_SIZE,
                 buckets=BUCKETS, max_seq_len=MAX_SEQ_LEN)
 
 

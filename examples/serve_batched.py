@@ -10,8 +10,7 @@ cache plus block-table-as-data design means steady-state traffic never
 recompiles, which ``Engine.stats.traces`` pins down.
 
 Run:  PYTHONPATH=src python examples/serve_batched.py [--lengths 7,16,33]
-Add ``--imc-mode sim --imc-noise-sigma 0.05`` for a noisy fabric, or
-``--kv ring`` for the legacy fixed-ring geometry (uniform lengths only).
+Add ``--imc-mode sim --imc-noise-sigma 0.05`` for a noisy fabric.
 ``--trace-out DIR`` runs the serving under the JAX profiler and writes its
 trace to DIR: the Server's spans (admission, decode launch, wait, logits
 fetch, sampling) beside the device's ops, on one clock; drop
@@ -43,9 +42,8 @@ def serve_fleet(cfg, params, rng, lengths, buckets, args):
 
     fleet = FleetEngine(LocalCoordinator(args.fleet_hosts),
                         noise_seed=args.seed)
-    server = FleetServer(cfg, params, fleet, slots=args.slots, kv=args.kv,
+    server = FleetServer(cfg, params, fleet, slots=args.slots,
                          block_size=args.block_size, buckets=buckets,
-                         attn_impl=args.attn_impl,
                          max_seq_len=max(buckets) + args.max_new)
     warm_traces = None
     total_tokens, t0 = 0, time.perf_counter()
@@ -94,10 +92,6 @@ def main():
                          "must be trace-free")
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--max-new", type=int, default=10)
-    ap.add_argument("--kv", default="paged", choices=["paged", "ring"])
-    ap.add_argument("--attn-impl", default=None, choices=["jnp", "pallas"],
-                    help="paged-decode attention engine (default: pallas on "
-                         "TPU, jnp elsewhere; pallas runs interpreted on CPU)")
     ap.add_argument("--block-size", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace-out", default=None, metavar="DIR",
@@ -116,8 +110,6 @@ def main():
     cfg = reduce_config(get_config(args.arch))
     cfg = apply_fabric_cli(ap, args, cfg, jitted_what="server")
     lengths = [int(x) for x in args.lengths.split(",")]
-    if args.kv == "ring":  # legacy geometry serves ONE uniform shape
-        lengths = [lengths[0]] * len(lengths)
     buckets = sorted({-(-n // 16) * 16 for n in lengths})
     rng = np.random.default_rng(0)
     params = init_params(jax.random.key(0), cfg)
@@ -135,8 +127,7 @@ def main():
                                      profiler_options=opts)
     with profile, engine.activate():
         server = Server(cfg, params, engine=engine, slots=args.slots,
-                        kv=args.kv, block_size=args.block_size,
-                        buckets=buckets, attn_impl=args.attn_impl,
+                        block_size=args.block_size, buckets=buckets,
                         max_seq_len=max(buckets) + args.max_new)
         warm_traces = None
         total_tokens, t0 = 0, time.perf_counter()
@@ -163,7 +154,7 @@ def main():
               f"generated {h.tokens}")
     print(f"{len(server.handles)} requests ({args.waves} waves, lengths "
           f"{lengths}) through {args.slots} slots "
-          f"[{args.kv}, attn={server.attn_impl}]; "
+          f"[paged, attn={server.attn_impl}]; "
           f"{total_tokens / dt:.1f} tok/s end-to-end; "
           f"{engine.stats.compiles} compiled steps, {engine.stats.traces} "
           f"traces, waves 2+ trace-free")

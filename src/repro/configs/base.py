@@ -61,19 +61,10 @@ class ModelConfig:
     native_dtype_dots: bool = True  # False = f32-cast attention dots (baseline)
     use_flash_kernel: bool = False  # Pallas flash-attn for train AND serving
     # prefill (TTFT); interpret-mode off-TPU
-    # Paged-decode attention engine: "jnp" = dense gather through the block
-    # table (the oracle), "pallas" = fused flash-decode kernel reading the
-    # pools directly (§Perf).  A ModelConfig field so Engine step-cache keys
-    # carry it — switching impls can never silently reuse a stale executable.
-    attn_impl: str = "jnp"
     # source provenance
     source: str = ""
 
     def __post_init__(self):
-        if self.attn_impl not in ("jnp", "pallas"):
-            raise ValueError(
-                f"{self.name}: attn_impl must be 'jnp' or 'pallas', "
-                f"got {self.attn_impl!r}")
         period = len(self.pattern)
         if (self.n_layers - len(self.tail)) % period != 0:
             raise ValueError(

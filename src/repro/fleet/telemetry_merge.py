@@ -12,11 +12,10 @@ coordinator's ``all_gather`` and merges them with
     average of per-host percentiles, which is a different (and wrong)
     statistic.
 
-``serving_slos(merged_registry, n_hosts=...)`` and
-``benchmarks/run.py --compare`` consume the merged view; the raw tagged
-snapshots stay available for per-host drill-down (the straggler gauges
-``straggler.ewma_s.host*`` are already per-host named, so they survive the
-merge unaliased).
+``serving_slos(merged_registry, n_hosts=...)`` consumes the merged view;
+the raw tagged snapshots stay available for per-host drill-down (the
+straggler gauges ``straggler.ewma_s.host*`` are already per-host named, so
+they survive the merge unaliased).
 """
 from __future__ import annotations
 

@@ -4,6 +4,8 @@
 dense gather oracle (:mod:`.ref` — bit-identical to the pre-kernel serving
 path); ``impl="pallas"`` runs the fused flash-decode kernel
 (:mod:`.paged_attn`), which reads the pools directly through the block table.
+``impl=None`` takes the platform's engine (:func:`resolve_impl`): the kernel
+on TPU, the gather, its reference, elsewhere.
 Both take the serving layout — q ``(B, 1, H, hd)``, pools
 ``(NB, bs, KV, hd)`` — and return ``(B, 1, H, hd)``; the kernel path regroups
 heads to the `_sdpa` convention ``(B, KV, rep, hd)`` (head ``h`` =
@@ -11,6 +13,7 @@ heads to the `_sdpa` convention ``(B, KV, rep, hd)`` (head ``h`` =
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import autotune
@@ -21,8 +24,14 @@ from repro.kernels.paged_attn.ref import paged_decode_ref
 ATTN_IMPLS = ("jnp", "pallas")
 
 
+def resolve_impl() -> str:
+    """The platform's decode-attention engine: ``"pallas"`` on TPU, else
+    ``"jnp"`` (off-TPU the kernel would run interpreted)."""
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+
+
 def paged_attention(q, k_pool, v_pool, block_table, pos, *, k_scale=None,
-                    v_scale=None, window: int = 0, impl: str = "jnp",
+                    v_scale=None, window: int = 0, impl: str | None = None,
                     blocks_per_step: int | None = None,
                     interpret: bool | None = None):
     """Paged decode attention against shared pools (post-scatter).
@@ -35,6 +44,8 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, *, k_scale=None,
     cached winner for this shape bucket (pool panels DMA'd per grid step;
     bit-identical across values).  Returns (B, 1, H, hd) in q.dtype.
     """
+    if impl is None:
+        impl = resolve_impl()
     if impl not in ATTN_IMPLS:
         raise ValueError(f"impl must be one of {ATTN_IMPLS}, got {impl!r}")
     if impl == "jnp":

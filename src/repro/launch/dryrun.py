@@ -132,17 +132,12 @@ def main():
     ap.add_argument("--paged-kv", action="store_true",
                     help="lower decode cells against the paged KV pool + "
                          "block table instead of the per-slot ring")
-    ap.add_argument("--attn-impl", default=None, choices=["jnp", "pallas"],
-                    help="paged-decode attention engine to lower (shorthand "
-                         "for --override attn_impl=...)")
     ap.add_argument("--fleet-hosts", type=int, default=1,
                     help="lower the cell on ONE virtual host's sub-mesh of "
                          "an N-host fleet instead of the global mesh")
     args = ap.parse_args()
 
     overrides = {}
-    if args.attn_impl:
-        overrides["attn_impl"] = args.attn_impl
     for ov in args.override:
         k, v = ov.split("=", 1)
         if v.lower() in ("true", "false"):

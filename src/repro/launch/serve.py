@@ -1,10 +1,9 @@
 """Serving CLI — drives :class:`repro.launch.server.Server` from the shell.
 
-The pre-paging ``BatchedServer`` (fixed-ring slots, uniform prompt length,
-batch-style ``run(requests)``) finished its deprecation cycle and is gone;
-``Server(kv="ring")`` reproduces the same fixed-ring geometry behind the
-typed ``submit``/``poll``/``drain`` API, with ragged admission, per-request
-budgets, and block-pool memory accounting on the ``kv="paged"`` path.
+The server keeps KV in paged block pools behind the typed
+``submit``/``poll``/``drain`` API, with ragged admission, per-request
+budgets, and block-pool memory accounting; decode attention runs on the
+engine the platform picks.
 
     python -m repro.launch.serve --arch qwen2.5-3b --requests 6
     python -m repro.launch.serve --arch qwen2.5-3b --requests 6 \
@@ -41,12 +40,6 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=12)
-    ap.add_argument("--kv", default="paged", choices=["paged", "ring"],
-                    help="paged block-table cache or the legacy fixed ring")
-    ap.add_argument("--attn-impl", default=None,
-                    choices=["jnp", "pallas"],
-                    help="paged-decode attention engine (default: pallas on "
-                         "TPU, jnp elsewhere)")
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0,
                     help="noise-key seed (noisy serve is reproducible in it)")
@@ -64,10 +57,8 @@ def main():
     rng = np.random.default_rng(0)
     params = init_params(jax.random.key(0), cfg)
     bucket = max(16, args.prompt_len)
-    server_kw = dict(slots=args.slots, kv=args.kv,
-                     block_size=args.block_size, buckets=(bucket,),
-                     attn_impl=args.attn_impl,
-                     max_seq_len=bucket + args.max_new)
+    server_kw = dict(slots=args.slots, block_size=args.block_size,
+                     buckets=(bucket,), max_seq_len=bucket + args.max_new)
     requests = [Request(
         rng.integers(0, cfg.vocab_size,
                      size=args.prompt_len).astype(np.int32),
@@ -97,7 +88,7 @@ def main():
     for h in handles:
         print(f"req{h.rid}: {len(h.tokens)} tokens -> {h.tokens[:8]}...")
     print(f"throughput: {ntok / max(dt, 1e-9):.1f} tok/s "
-          f"({args.kv} lockstep decode, attn={server.attn_impl}; "
+          f"(paged lockstep decode, attn={server.attn_impl}; "
           f"{traces} traces)")
     where = f"fleet (n_hosts={slos['n_hosts']})" if "n_hosts" in slos \
         else "server"

@@ -23,9 +23,10 @@ queue over a **paged KV cache** (see :mod:`repro.models.kv_cache`):
     (greedy decode makes recovered streams bit-identical), and every decode
     step's wall time feeds the Engine's straggler monitor.
 
-``kv="ring"`` keeps the legacy geometry (one fixed ring per slot, uniform
-prompt length) behind the same API — it is the oracle the paged path is
-tested against and the baseline the benchmarks compare throughput with.
+The paged pools are the only KV geometry.  Decode attention over them runs
+on the engine the platform picks (:func:`repro.kernels.paged_attn.ops
+.resolve_impl`): the ``paged_attn`` kernel on TPU, the jnp gather, its
+reference, elsewhere.
 
 Serving SLOs are first-class telemetry (all host-side; nothing recorded here
 ever blocks on the device beyond the block the decode loop already does for
@@ -72,18 +73,17 @@ table only when it changed.  Counters: ``server.decode.token_uploads``,
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.kernels.paged_attn import ops as paged_attn_ops
 from repro.launch.engine import Engine
-from repro.models.kv_cache import (BlockAllocator, broadcast_slots,
-                                   init_paged_cache)
+from repro.models.kv_cache import BlockAllocator, init_paged_cache
 from repro.runtime.fault_tolerance import InjectedFailure
 from repro.telemetry import clock, span
 
@@ -127,25 +127,19 @@ class Handle:
 
 
 class Server:
-    """Continuous-batching server over a paged (or legacy ring) KV cache.
+    """Continuous-batching server over a paged KV cache.
 
     Parameters
     ----------
     slots: max concurrent requests (the lockstep decode batch).
-    kv: ``"paged"`` (block tables, ragged admission) or ``"ring"``
-        (legacy fixed-ring oracle; requires uniform ``len(prompt)`` and
-        ``max_new_tokens`` across requests).
+    kv: the KV geometry; ``"paged"`` (block tables, ragged admission) is
+        the only one.
     block_size / num_blocks: paged pool geometry.  ``num_blocks`` defaults
         to ``slots * ceil(max_seq_len / block_size)`` (never blocks on
         admission); pass less to exercise queueing.
     buckets: padded prompt lengths to compile prefill for (ascending).
     max_seq_len: hard per-request cap on ``len(prompt) + max_new_tokens``;
         fixes the decode step's logical attention span.
-    attn_impl: paged-decode attention engine — ``"jnp"`` (dense gather) or
-        ``"pallas"`` (fused flash-decode kernel over the block table).
-        ``None`` (default) picks the kernel on TPU and keeps the config's
-        value elsewhere (off-TPU the kernel would run interpreted —
-        correct but slow, so only tests opt in).  Ignored for ``kv="ring"``.
     host: this server's fleet host index.  Decode-step wall times feed the
         Engine's straggler monitor under this index, so a fleet of Servers
         sharing one monitor produces REAL per-host entries instead of
@@ -158,21 +152,14 @@ class Server:
                  num_blocks: Optional[int] = None,
                  buckets: Sequence[int] = (16, 32, 64),
                  max_seq_len: Optional[int] = None,
-                 attn_impl: Optional[str] = None,
                  host: int = 0,
                  fail_at: Optional[Sequence[int]] = None):
-        if kv not in ("paged", "ring"):
-            raise ValueError(f"kv must be 'paged' or 'ring', got {kv!r}")
-        if attn_impl is None and kv == "paged" and \
-                jax.default_backend() == "tpu":
-            attn_impl = "pallas"
-        if attn_impl is not None and attn_impl != cfg.attn_impl:
-            cfg = dataclasses.replace(cfg, attn_impl=attn_impl)
-        self.attn_impl = cfg.attn_impl if kv == "paged" else "ring"
+        if kv != "paged":
+            raise ValueError(f"kv must be 'paged' (the ring geometry was "
+                             f"removed), got {kv!r}")
         self.cfg, self.params = cfg, params
         self.engine = engine or Engine()
         self.slots = slots
-        self.kv = kv
         self.host = host
         self.buckets = tuple(sorted(buckets))
         self.max_seq_len = max_seq_len or (max(self.buckets) + 64)
@@ -190,7 +177,6 @@ class Server:
         self.decode_s = 0.0  # accumulated lockstep-decode wall time
         self._fail_at = set(fail_at or ())
         self._tick = 0  # one noise key per jitted invocation
-        self._ring_shape: Optional[Tuple[int, int]] = None
         self._decode = self.engine.decode_step(cfg)
         self._admit_fn = self.engine.admit_step(cfg)
         self._prefills: Dict[int, object] = {}
@@ -216,12 +202,16 @@ class Server:
         self._m_host_rows = reg.counter("server.decode.host_rows")
         self._m_table_uploads = reg.counter("server.decode.table_uploads")
 
+    @property
+    def attn_impl(self) -> str:
+        """The decode-attention engine the platform picked."""
+        return paged_attn_ops.resolve_impl()
+
     def _feed_gauges(self):
         """Occupancy from the allocator's free list + queue depth (host ints)."""
         self._m_queue.set(len(self.queued))
-        if self.kv == "paged":
-            used = self.num_blocks - self.alloc.num_free
-            self._m_occupancy.set(used / self.num_blocks)
+        used = self.num_blocks - self.alloc.num_free
+        self._m_occupancy.set(used / self.num_blocks)
 
     # ----------------------------------------------------------- public API
     def submit(self, request: Request) -> Handle:
@@ -231,31 +221,20 @@ class Server:
         self.handles.append(h)
         plen = int(len(request.prompt))
         worst = plen + request.max_new_tokens
-        if self.kv == "paged":
-            if plen > max(self.buckets):
-                h.status, h.reason = "rejected", (
-                    f"prompt length {plen} exceeds the largest prefill "
-                    f"bucket {max(self.buckets)}")
-                self._m_rejected.inc()
-                return h
-            if worst > self.max_seq_len or \
-                    self.alloc.blocks_for(worst) > self.num_blocks:
-                h.status, h.reason = "rejected", (
-                    f"worst case {worst} tokens can never fit "
-                    f"(max_seq_len={self.max_seq_len}, "
-                    f"pool={self.num_blocks}x{self.block_size})")
-                self._m_rejected.inc()
-                return h
-        else:
-            if self._ring_shape is None:  # first request pins the geometry
-                self._ring_shape = (plen, request.max_new_tokens)
-            if (plen, request.max_new_tokens) != self._ring_shape:
-                h.status, h.reason = "rejected", (
-                    f"kv='ring' serves one uniform shape "
-                    f"{self._ring_shape}, got {(plen, request.max_new_tokens)}"
-                    " — use kv='paged' for ragged traffic")
-                self._m_rejected.inc()
-                return h
+        if plen > max(self.buckets):
+            h.status, h.reason = "rejected", (
+                f"prompt length {plen} exceeds the largest prefill "
+                f"bucket {max(self.buckets)}")
+            self._m_rejected.inc()
+            return h
+        if worst > self.max_seq_len or \
+                self.alloc.blocks_for(worst) > self.num_blocks:
+            h.status, h.reason = "rejected", (
+                f"worst case {worst} tokens can never fit "
+                f"(max_seq_len={self.max_seq_len}, "
+                f"pool={self.num_blocks}x{self.block_size})")
+            self._m_rejected.inc()
+            return h
         self.queued.append(h)
         self._m_admitted.inc()
         self._m_queue.set(len(self.queued))
@@ -291,14 +270,8 @@ class Server:
     # ------------------------------------------------------------ admission
     def _prefill_step(self, bucket: int):
         if bucket not in self._prefills:
-            if self.kv == "paged":
-                step = self.engine.prefill_step(self.cfg, max_new_tokens=0,
-                                                bucket=bucket)
-            else:
-                plen, max_new = self._ring_shape
-                step = self.engine.prefill_step(self.cfg,
-                                                max_new_tokens=max_new)
-            self._prefills[bucket] = step
+            self._prefills[bucket] = self.engine.prefill_step(
+                self.cfg, max_new_tokens=0, bucket=bucket)
         return self._prefills[bucket]
 
     def _bucket_for(self, plen: int) -> int:
@@ -321,20 +294,19 @@ class Server:
                 continue
             h = self.queued[0]
             plen = len(h.request.prompt)
-            if self.kv == "paged":
-                need = self.alloc.blocks_for(plen)
-                reserve = self.alloc.blocks_for(
-                    plen + h.request.max_new_tokens) - need
-                if not self.alloc.can_admit(need + reserve):
-                    return  # FIFO: wait for blocks instead of starving h
-                self.alloc.alloc(slot, need, reserve=reserve)
+            need = self.alloc.blocks_for(plen)
+            reserve = self.alloc.blocks_for(
+                plen + h.request.max_new_tokens) - need
+            if not self.alloc.can_admit(need + reserve):
+                return  # FIFO: wait for blocks instead of starving h
+            self.alloc.alloc(slot, need, reserve=reserve)
             self.queued.pop(0)
             self._admit(h, slot)
 
     def _admit(self, h: Handle, slot: int):
         req = h.request
         plen = len(req.prompt)
-        bucket = None if self.kv == "ring" else self._bucket_for(plen)
+        bucket = self._bucket_for(plen)
         self._m_queue_wait.observe(clock() - h._t_submit)
         with span("server.admit", rid=h.rid, bucket=bucket, len=plen):
             with span("server.admit.launch"):
@@ -353,30 +325,21 @@ class Server:
         if self._finished(h):
             self._retire(h)
 
-    def _launch_prefill(self, req: Request, slot: int, bucket: Optional[int]):
-        """Upload the (padded) prompt, dispatch its prefill and the merge of
+    def _launch_prefill(self, req: Request, slot: int, bucket: int):
+        """Upload the padded prompt, dispatch its prefill and the merge of
         its cache into ``slot``; returns the prefill's logits, not waited
         on."""
         prompt = np.asarray(req.prompt, np.int32)
-        if self.kv == "paged":
-            padded = np.zeros((bucket,), np.int32)
-            padded[:len(prompt)] = prompt
-            batch = {"tokens": jnp.asarray(padded[None]),
-                     "length": jnp.asarray(len(prompt), jnp.int32)}
-            table_row = jnp.asarray(self.alloc.table_row(slot))
-        else:
-            batch = {"tokens": jnp.asarray(prompt[None])}
-            table_row = jnp.zeros((self.max_blocks,), jnp.int32)  # unused
+        padded = np.zeros((bucket,), np.int32)
+        padded[:len(prompt)] = prompt
+        batch = {"tokens": jnp.asarray(padded[None]),
+                 "length": jnp.asarray(len(prompt), jnp.int32)}
+        table_row = jnp.asarray(self.alloc.table_row(slot))
         logits, cache1 = self._prefill_step(bucket)(
             self.params, batch, self._next_key(slot))
         if self.cache is None:
-            if self.kv == "paged":
-                self.cache = init_paged_cache(cache1, self.slots,
-                                              self.num_blocks,
-                                              self.block_size)
-            else:
-                self.cache = jax.tree.map(
-                    lambda o: broadcast_slots(o, self.slots), cache1)
+            self.cache = init_paged_cache(cache1, self.slots,
+                                          self.num_blocks, self.block_size)
         self.cache = self._admit_fn(self.cache, cache1, table_row,
                                     jnp.asarray(slot, jnp.int32))
         return logits
@@ -410,8 +373,7 @@ class Server:
         if len(h.tokens) > 1:  # TPOT covers decode tokens only
             self._m_tpot.observe(
                 (clock() - h._t_first) / (len(h.tokens) - 1))
-        if self.kv == "paged":
-            self.alloc.release(h.slot)
+        self.alloc.release(h.slot)
         self.active[h.slot] = None
 
     def _tokens(self):
@@ -446,17 +408,14 @@ class Server:
                if h is not None and h.request.temperature > 0]
         with span("server.decode", tick=tick, rows=rows):
             with span("server.decode.inputs"):
-                if self.kv == "paged":  # grow across a block boundary
-                    for i, h in enumerate(self.active):
-                        while h is not None and \
-                                self.alloc.blocks_for(h._next_pos + 1) > \
-                                len(self.alloc.slot_blocks(i)):
-                            self.alloc.append(i)
+                for i, h in enumerate(self.active):  # grow across a block
+                    while h is not None and \
+                            self.alloc.blocks_for(h._next_pos + 1) > \
+                            len(self.alloc.slot_blocks(i)):
+                        self.alloc.append(i)
                 t0 = clock()  # the step's time: key and uploads to fetch
                 args = (self.params, self.cache, self._tokens(),
-                        self._next_key())
-                if self.kv == "paged":
-                    args += (self._block_table(),)
+                        self._next_key(), self._block_table())
             with span("server.decode.dispatch"):
                 logits, self.cache = self._decode(*args)
                 self._tok = self._ids(logits)
@@ -502,8 +461,7 @@ class Server:
                 h.status, h.slot, h._rng = "queued", None, None
                 requeued.append(h)
             self.active[i] = None
-            if self.kv == "paged":
-                self.alloc.release(i)
+            self.alloc.release(i)
         self.cache = None
         self.queued = requeued + self.queued
         self.recoveries += 1

@@ -255,7 +255,7 @@ def attn_prefill(params, x, *, n_heads, n_kv_heads, head_dim, rope_theta,
 
 def _attn_decode_paged(params, x, cache: PagedAttnCache, pos, block_table, *,
                        n_heads, n_kv_heads, head_dim, rope_theta,
-                       window: int = 0, attn_impl: str = "jnp", **imc):
+                       window: int = 0, **imc):
     """One-token decode against the shared paged pools.
 
     x: (B, 1, D); pos: (B,) int32; block_table: (B, MB) int32, -1 = empty.
@@ -267,12 +267,14 @@ def _attn_decode_paged(params, x, cache: PagedAttnCache, pos, block_table, *,
     IS position ``i`` (tables are dense prefixes), so the validity mask is
     just ``i <= pos`` limited to allocated blocks.
 
-    ``attn_impl="jnp"`` is the dense gather path — bit-identical to the ring
-    oracle because the extra masked rows contribute exact zeros.
-    ``attn_impl="pallas"`` runs the fused flash-decode kernel: it reads the
-    post-scatter pools block-by-block through the table (the gathered span
-    never touches HBM), within one output ulp of the jnp path (online
-    softmax rounds its rescaling differently from one-shot softmax).
+    The platform picks the engine
+    (:func:`repro.kernels.paged_attn.ops.resolve_impl`).  Off TPU the dense
+    gather runs — bit-identical to the ring cache because the extra masked
+    rows contribute exact zeros.  On TPU the fused flash-decode kernel
+    reads the post-scatter pools block-by-block through the table (the
+    gathered span never touches HBM), within one output ulp of the gather
+    (online softmax rounds its rescaling differently from one-shot
+    softmax).
     """
     b = x.shape[0]
     nb, bs = cache.k.shape[0], cache.k.shape[1]
@@ -304,15 +306,14 @@ def _attn_decode_paged(params, x, cache: PagedAttnCache, pos, block_table, *,
 
     out = paged_attention(q, new_cache.k, new_cache.v, block_table, p,
                           k_scale=new_cache.k_scale,
-                          v_scale=new_cache.v_scale, window=window,
-                          impl=attn_impl)
+                          v_scale=new_cache.v_scale, window=window)
     y = dense(params["wo"], out.reshape(b, 1, -1), **imc)
     return y, new_cache
 
 
 def attn_decode(params, x, cache, pos, *, n_heads, n_kv_heads,
                 head_dim, rope_theta, window: int = 0, block_table=None,
-                attn_impl: str = "jnp", **imc):
+                **imc):
     """One-token decode. x: (B, 1, D); pos: scalar int32 OR (B,) int32 —
     per-row positions support continuous batching, where slots admitted at
     different ticks sit at different sequence positions.
@@ -322,15 +323,13 @@ def attn_decode(params, x, cache, pos, *, n_heads, n_kv_heads,
     layers T_alloc == context so the slot is just ``pos``).  Paged path
     (``cache`` a :class:`PagedAttnCache`): routes through the per-slot
     ``block_table`` instead — the ring stays the tested oracle.
-    ``attn_impl`` selects the paged engine ("jnp" dense gather oracle /
-    "pallas" fused flash-decode kernel); the ring path ignores it.
     """
     if isinstance(cache, PagedAttnCache):
         assert block_table is not None, "paged decode needs a block table"
         return _attn_decode_paged(params, x, cache, pos, block_table,
                                   n_heads=n_heads, n_kv_heads=n_kv_heads,
                                   head_dim=head_dim, rope_theta=rope_theta,
-                                  window=window, attn_impl=attn_impl, **imc)
+                                  window=window, **imc)
     b = x.shape[0]
     t_alloc = cache.k.shape[1]
     pos = jnp.asarray(pos, jnp.int32)

@@ -1,9 +1,8 @@
 """Paged KV cache: fixed-size blocks, a free-list allocator, block tables.
 
-The serving problem this solves: the pre-paging server (today's
-``Server(kv="ring")``) gave every slot one fixed-length ring of
-``prompt_len + max_new`` K/V rows, so heterogeneous traffic paid worst-case
-memory per slot and a single shared ``prompt_len``.
+The serving problem this solves: the pre-paging server gave every slot one
+fixed-length ring of ``prompt_len + max_new`` K/V rows, so heterogeneous
+traffic paid worst-case memory per slot and a single shared ``prompt_len``.
 Paging decouples *logical* sequence length from *physical* cache geometry —
 the same move the reconfigurable IMC macros make for array geometry: a pool
 of ``num_blocks`` fixed-size blocks per attention layer is shared by all

@@ -126,8 +126,7 @@ def _mix(cfg, params, x, kind, mode, cache, pos, prefill_extra=0,
                                 true_len=true_len,
                                 use_flash=cfg.use_flash_kernel, **kw)
         return attn_decode(params["attn"], x, cache, pos,
-                           block_table=block_table,
-                           attn_impl=cfg.attn_impl, **kw)
+                           block_table=block_table, **kw)
     if kind == "rglru":
         if mode in ("train", "prefill"):
             y, (h, cs) = rglru_forward(params["rglru"], x, **imc)

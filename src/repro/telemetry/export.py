@@ -78,12 +78,11 @@ def serving_slos(registry: Optional[Registry] = None,
     served (e.g. train-only runs).
 
     ``attn_impl`` tags which decode-attention engine produced the numbers
-    (pass :attr:`Server.attn_impl`); it rides along in the row so
-    ``benchmarks/run.py --compare`` never diffs jnp-path SLOs against
-    kernel-path SLOs silently.  ``n_hosts`` does the same for fleet runs:
-    pass the host count when ``registry`` is a merged fleet view
+    (pass :attr:`Server.attn_impl`), so a row says whether the kernel or the
+    jnp gather served it.  ``n_hosts`` does the same for fleet runs: pass
+    the host count when ``registry`` is a merged fleet view
     (:meth:`repro.telemetry.Registry.merge`), so single-host SLOs are never
-    compared against fleet SLOs under one key.
+    read as fleet SLOs.
     """
     snap = snapshot(registry)
     hists, gauges = snap["histograms"], snap["gauges"]

@@ -41,9 +41,9 @@ def _requests(cfg, n, seed=0):
             for _ in range(n)]
 
 
-def _ring_server(cfg, params, eng, **kw):
-    """Fixed-ring serving geometry (the pre-paging shape) behind Server."""
-    return Server(cfg, params, engine=eng, slots=2, kv="ring", **kw)
+def _uniform_server(cfg, params, eng, **kw):
+    """A two-slot Server whose one bucket holds the uniform ``PROMPT``."""
+    return Server(cfg, params, engine=eng, slots=2, buckets=(PROMPT,), **kw)
 
 
 # ----------------------------------------------------------- compat shim
@@ -113,7 +113,7 @@ def test_batched_serve_matches_sequential_decode(cfg, params):
     reqs = _requests(cfg, 5)
     eng = Engine()
     with eng.activate():
-        server = _ring_server(cfg, params, eng)
+        server = _uniform_server(cfg, params, eng)
         handles = [server.submit(r) for r in reqs]
         server.drain()
     for h in handles:
@@ -124,7 +124,7 @@ def test_batched_serve_matches_sequential_decode(cfg, params):
 def test_serve_steady_state_no_recompiles(cfg, params):
     eng = Engine()
     with eng.activate():
-        server = _ring_server(cfg, params, eng)
+        server = _uniform_server(cfg, params, eng)
         server.submit(_requests(cfg, 1)[0])
         server.drain()  # warm every executable (prefill/admit/decode)
         warm = eng.stats.traces
@@ -141,11 +141,11 @@ def test_serve_steady_state_no_recompiles(cfg, params):
 def test_serve_fault_injection_recovers_identical_streams(cfg, params):
     eng = Engine()
     with eng.activate():
-        server = _ring_server(cfg, params, eng)
+        server = _uniform_server(cfg, params, eng)
         for r in _requests(cfg, 3):
             server.submit(r)
         baseline = server.drain()
-        crashed = _ring_server(cfg, params, eng, fail_at=(1,))
+        crashed = _uniform_server(cfg, params, eng, fail_at=(1,))
         for r in _requests(cfg, 3):
             crashed.submit(r)
         recovered = crashed.drain()

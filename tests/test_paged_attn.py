@@ -10,18 +10,19 @@ sentinel rows and partially-filled last blocks.  Expected agreement:
   * bf16 pools: within ~2 output ulp.  Exact bit-equality is unattainable in
     principle: online softmax rescales past contributions by exp(m_old-m_new)
     while one-shot softmax exponentiates once, so the two round differently.
-    The serving default (``attn_impl="jnp"``) remains the bit-exact path.
+    The CPU's engine (the jnp gather) remains the bit-exact path.
   * int8 pools: atol 1e-2 (quantization noise dominates; the kernel
     dequantizes in-register, the oracle pre-dequantizes — same scales).
 
 Everything runs in Pallas interpret mode on CPU (``interpret=None`` resolves
 via ``kernels.compat``), so CI exercises the kernel body on every PR.
 
-Wiring tests pin the end-to-end story: ``attn_decode(attn_impl=...)`` parity
-at the layer level with a shared (bit-identical) cache scatter, ModelConfig
-validation, Server impl selection, zero steady-state retraces through the
-Server with ``attn_impl="pallas"``, and flash prefill (``use_flash``)
-producing the same decode cache bit-for-bit.
+Wiring tests pin the end-to-end story: ``attn_decode`` parity between the
+two engines at the layer level with a shared (bit-identical) cache scatter,
+the platform's choice of engine (``resolve_impl``, reported by
+``Server.attn_impl``), zero steady-state retraces through the Server with the
+kernel pinned, and flash prefill (``use_flash``) producing the same decode
+cache bit-for-bit.
 """
 import dataclasses
 
@@ -31,6 +32,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduce_config
+from repro.kernels.paged_attn import ops
 from repro.kernels.paged_attn.ops import paged_attention
 from repro.launch.engine import Engine
 from repro.launch.server import Request, Server
@@ -191,8 +193,13 @@ def test_kernel_parity_hypothesis():
 
 
 # ---------------------------------------------------------- layer-level wiring
+def _pin_impl(monkeypatch, impl):
+    """Make the platform's engine selector answer ``impl``."""
+    monkeypatch.setattr(ops, "resolve_impl", lambda: impl)
+
+
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_attn_decode_paged_impl_parity(kv_dtype):
+def test_attn_decode_paged_impl_parity(kv_dtype, monkeypatch):
     """Through ``attn_decode``: both impls share one scatter (caches must be
     bit-identical) and agree on the mixed output within kernel tolerance."""
     rng = np.random.default_rng(6)
@@ -213,19 +220,14 @@ def test_attn_decode_paged_impl_parity(kv_dtype):
         cache = PagedAttnCache(kp, vp)
     kw = dict(n_heads=H, n_kv_heads=KV, head_dim=hd, rope_theta=1e4,
               block_table=tbl)
-    y_j, c_j = attn_decode(params, x, cache, pos, attn_impl="jnp", **kw)
-    y_p, c_p = attn_decode(params, x, cache, pos, attn_impl="pallas", **kw)
+    _pin_impl(monkeypatch, "jnp")
+    y_j, c_j = attn_decode(params, x, cache, pos, **kw)
+    _pin_impl(monkeypatch, "pallas")
+    y_p, c_p = attn_decode(params, x, cache, pos, **kw)
     for a, b in zip(jax.tree.leaves(c_j), jax.tree.leaves(c_p)):
         assert jnp.array_equal(a, b), "impl switch changed the cache scatter"
     tol = {"bf16": 5e-5, "int8": 1e-2}[kv_dtype]  # f32 activations
     assert _err(y_j, y_p) <= tol
-
-
-def test_model_config_validates_attn_impl():
-    cfg = reduce_config(get_config("qwen2.5-3b"))
-    with pytest.raises(ValueError, match="attn_impl"):
-        dataclasses.replace(cfg, attn_impl="cuda")
-    assert dataclasses.replace(cfg, attn_impl="pallas").attn_impl == "pallas"
 
 
 # --------------------------------------------------------------- server-level
@@ -252,35 +254,30 @@ def _serve_wave(server, cfg, rng):
     return hs
 
 
-def test_server_attn_impl_selection(cfg, params):
+@pytest.mark.parametrize("backend,impl", [("cpu", "jnp"), ("tpu", "pallas")])
+def test_server_attn_impl_selection(cfg, params, monkeypatch, backend, impl):
+    """The platform picks the engine: the kernel on TPU, the gather (never
+    the interpreted kernel) elsewhere; the Server reports that choice."""
     eng = Engine()
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.resolve_impl() == impl
     with eng.activate():
         srv = Server(cfg, params, engine=eng, slots=2, block_size=8,
-                     buckets=(16,), attn_impl="pallas", max_seq_len=24)
-        assert srv.attn_impl == "pallas"
-        assert srv.cfg.attn_impl == "pallas"  # carried in Engine cache keys
-        # default: kernel on TPU, else keep the config's (jnp) path — the
-        # interpreter is opt-in, never a silent serving default
-        expect = ("pallas" if jax.default_backend() == "tpu"
-                  else cfg.attn_impl)
-        assert Server(cfg, params, engine=eng, slots=2, block_size=8,
-                      buckets=(16,), max_seq_len=24).attn_impl == expect
-        # the ring geometry has no paged engine to select
-        assert Server(cfg, params, engine=eng, slots=2, kv="ring",
-                      buckets=(16,), max_seq_len=24).attn_impl == "ring"
+                     buckets=(16,), max_seq_len=24)
+    assert srv.attn_impl == impl
 
 
-def test_server_pallas_zero_steady_state_retraces(cfg, params):
-    """Two identical ragged waves through attn_impl='pallas' (+ flash
+def test_server_pallas_zero_steady_state_retraces(cfg, params, monkeypatch):
+    """Two identical ragged waves through the pinned kernel (+ flash
     prefill): wave 2 must reuse every compiled step — the kernel rides inside
     the jitted decode step without adding trace keys."""
+    _pin_impl(monkeypatch, "pallas")
     c = dataclasses.replace(cfg, use_flash_kernel=True)
     eng = Engine()
     rng = np.random.default_rng(0)
     with eng.activate():
         srv = Server(c, params, engine=eng, slots=2, block_size=8,
-                     buckets=(16, 48), attn_impl="pallas",
-                     max_seq_len=48 + MAX_NEW)
+                     buckets=(16, 48), max_seq_len=48 + MAX_NEW)
         _serve_wave(srv, c, rng)
         warm = eng.stats.traces
         _serve_wave(srv, c, rng)

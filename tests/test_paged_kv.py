@@ -244,18 +244,19 @@ def test_fault_requeue_replays_identical_streams(cfg, params):
 
 
 def test_ring_mode_is_the_same_api(cfg, params):
-    """kv='ring' serves uniform traffic behind submit/poll/drain too."""
+    """Uniform traffic, which the removed ring geometry served, runs through
+    the paged pools and equals the oracle; asking for the ring raises."""
     prompts = _prompts(cfg, (16, 16, 16))
     eng = Engine()
     with eng.activate():
-        server = Server(cfg, params, engine=eng, slots=2, kv="ring")
+        with pytest.raises(ValueError, match="ring"):
+            Server(cfg, params, engine=eng, slots=2, kv="ring")
+        server = Server(cfg, params, engine=eng, slots=2, buckets=(16,))
         handles = [server.submit(Request(p, max_new_tokens=MAX_NEW))
                    for p in prompts]
-        ragged = server.submit(Request(_prompts(cfg, [7])[0],
-                                       max_new_tokens=MAX_NEW))
         server.drain()
-    assert ragged.status == "rejected" and "uniform" in ragged.reason
     for h in handles:
+        assert h.done
         assert h.tokens == _oracle(cfg, params, h.request.prompt)
 
 
@@ -265,9 +266,9 @@ def _decode_counts(reg):
             for k in ("token_uploads", "host_rows", "table_uploads")}
 
 
-@pytest.mark.parametrize("kv,lengths", [("paged", LENGTHS),
-                                        ("ring", (16, 16, 16))])
-def test_device_chained_tokens_match_the_host_argmax_oracle(cfg, params, kv,
+@pytest.mark.parametrize("lengths", [LENGTHS, (16, 16, 16)],
+                         ids=["ragged", "uniform"])
+def test_device_chained_tokens_match_the_host_argmax_oracle(cfg, params,
                                                             lengths):
     """Each tick's greedy ids feed the next tick on the device; the host
     uploads a token array only after an admission.  The streams equal the
@@ -277,7 +278,7 @@ def test_device_chained_tokens_match_the_host_argmax_oracle(cfg, params, kv,
     eng = Engine(registry=reg)
     prompts = _prompts(cfg, lengths)
     with eng.activate():
-        server = _server(cfg, params, eng, kv=kv)
+        server = _server(cfg, params, eng)
         for p in prompts:
             server.submit(Request(p, max_new_tokens=MAX_NEW))
         server.drain()
