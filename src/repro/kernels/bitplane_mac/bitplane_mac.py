@@ -4,9 +4,9 @@ Generalizes ``repro.kernels.rbl_decode`` from one bit-plane pair to all
 ``bits_a x bits_w`` pairs: per output tile the kernel sweeps plane pairs and
 K-blocks (the activation planes ride the row axis, see below), and for every
 (pair, K-block) it runs the paper's whole evaluation pipeline — per-8-row-
-group binary MAC counts, charge-sharing RBL voltage, comparator thermometer
-decode, and the ``2^(p+q)``-weighted digital shift-accumulate — without
-leaving VMEM:
+group binary MAC counts, charge-sharing RBL voltage (tabulated on the count
+levels, see below), comparator thermometer decode, and the
+``2^(p+q)``-weighted digital shift-accumulate — without leaving VMEM:
 
   out[m, n] = sum_{p,q} 2^{p+q} sum_g decode( V( sum_r a[p, m, g*rows+r]
                                                    * w[q, g*rows+r, n] ) )
@@ -26,11 +26,22 @@ Implementation notes (TPU adaptation):
     accumulator), row/N tiles are parallel.  Each (bk, bn) weight-plane
     block is read once per row tile, for every activation plane at once;
     VMEM never holds more than one (bm, bk) + (bk, bn) plane slice.
-  * group MACs are one (bm, rows) x (rows, bn) bf16 dot per row-group,
-    unrolled over the ``bk // rows`` groups of a K-block and stacked into
-    (groups, bm, bn) counts (Mosaic lowers no in-kernel reshape that splits
-    the lane axis into groups); V(k) is the fitted two-regime physics on
-    the VPU; the comparator bank is ``rows`` broadcast compares.
+  * the comparator bank runs in the count domain.  A noise-free count k
+    takes only the values 0..rows and V(k) falls with k, so comparator i
+    (V <= thr[i]) fires exactly when k >= c_i, c_i = #{j : V(j) > thr[i]}.
+    The wrapper evaluates the voltage map on the rows + 1 levels once per
+    call and hands the kernel c_i as int32 scalars (scalar prefetch); the
+    thresholds stay data, so corner-re-tuned references (paper §IV-C) are a
+    data, not code, change.  Every comparator still runs on every count.
+  * four row-groups share one dot (:func:`group_pack`): group g's
+    activation bits are scaled by 64^(g mod 4) (exact in bf16), so one
+    (bm, 4*rows) x (4*rows, bn) dot returns k0 + 64 k1 + 4096 k2 +
+    262144 k3, exact in f32 (below 2^24).  Comparator i is then one add of
+    (32 - c_i) to every 6-bit field and one mask of the fields' top bits;
+    the masked bits of several packs add up before one shift and unpack,
+    as long as no field can pass 63.  Counts past 31 (``rows`` > 31), or a
+    K-block of whole groups not divisible by four, take one group per dot
+    with the same compare on one wider field.
   * the plane weight 2^(p+q) is split: each step adds its 2^q-weighted
     decode (q from ``pl.program_id``), and the tile's flush shifts each row
     by its p, found from the row's global index, so the inner loop costs
@@ -38,8 +49,10 @@ Implementation notes (TPU adaptation):
     lose bit-exactness beyond 2^24 for deep-K 8-bit operands); the kernel
     writes each row's partial sum, at most 2^(PA-1) * (2^PW - 1) * K, and
     the wrapper adds the PA row blocks.
-  * thresholds arrive as a (1, rows) block so corner-re-tuned references
-    (paper §IV-C) stay a data, not code, change.
+  * whole zero groups of K padding (past ``ceil(K / rows)``) exist only in
+    the block grid, not in the macro: in the last K-block their fields get
+    no lift, so no comparator fires on them even when a detuned reference
+    lies above V(0).
 
 The NOISY sibling (:func:`bitplane_mac_noisy_raw`) keeps the unfolded
 layout — activation planes [PA, M, K] on a (M/bm, N/bn, PA*PW, K/bk) grid,
@@ -62,12 +75,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import constants as C
-from repro.kernels.common import (decode_counts, decode_counts_noisy,
-                                  make_normal_sampler)
+from repro.kernels.common import decode_counts_noisy, make_normal_sampler
 
 
 def _group_counts(a, b, rows: int):
@@ -85,24 +98,89 @@ def _group_counts(a, b, rows: int):
         for lo in range(0, a.shape[1], rows)])
 
 
-def _make_kernel(rows: int, m_pad: int, bits_a: int):
-    def kernel(a_ref, b_ref, thr_ref, o_ref, acc_ref):
+def group_pack(rows: int, bk: int) -> tuple[int, int]:
+    """(groups per dot, field bits) of the count-domain comparator bank for
+    ``rows``-row groups in a ``bk``-deep K-block.
+
+    Four groups share one dot while a count fits a 6-bit field with its top
+    bit spare (``rows <= 31``) and the block splits into whole packs: the
+    MXU then returns k0 + 64 k1 + 4096 k2 + 262144 k3, an integer below
+    2^24 and so exact in f32.  Otherwise each group has a dot of its own
+    and one field whose top bit lies just above ``rows``."""
+    if rows <= 31 and (bk // rows) % 4 == 0:
+        return 4, 6
+    return 1, rows.bit_length() + 1
+
+
+def _make_kernel(rows: int, bk: int, m_pad: int, bits_a: int,
+                 last_valid: int | None):
+    pack, fbits = group_pack(rows, bk)
+    span, packs = pack * rows, bk // (pack * rows)
+    half = 1 << (fbits - 1)
+    ones = sum(1 << (fbits * f) for f in range(pack))  # a 1 in every field
+    tops = ones << (fbits - 1)  # every field's top bit
+    # decoded packs summed before a field could pass 2^fbits - 1
+    per_sum = ((1 << fbits) - 1) // rows if pack > 1 else packs
+
+    def field_sum(x):
+        """The sum over fields of x, each field holding a count at its top
+        bit."""
+        x = x >> (fbits - 1)
+        out = x >> (fbits * (pack - 1))
+        for f in range(pack - 1):
+            out = out + ((x >> (fbits * f)) & ((1 << fbits) - 1))
+        return out
+
+    def kernel(ref_ref, a_ref, b_ref, scale_ref, o_ref, acc_ref):
         i = pl.program_id(0)
         q = pl.program_id(2)
         kk = pl.program_id(3)
+        last = kk == pl.num_programs(3) - 1
 
         @pl.when((q == 0) & (kk == 0))
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        dec = decode_counts(_group_counts(a_ref[...], b_ref[0], rows),
-                            thr_ref[...], rows)
+        # Group g's activation bits carry 64^(g mod 4) (powers of two, exact
+        # in bf16), so one dot over a pack's 4*rows columns gives its four
+        # counts in four fields.
+        a = (a_ref[...].astype(jnp.float32) * scale_ref[...]).astype(
+            jnp.bfloat16)
+        b = b_ref[0].astype(jnp.bfloat16)
+        # Comparator r fires on count k >= c_r, which sets the field's top
+        # bit of k + half - c_r: one add and one mask per comparator for
+        # every field of the pack.  The masked bits are summed as they are
+        # and shifted down once per sum.
+        lifts = [half - ref_ref[r] for r in range(rows)]
+        full = [x * ones for x in lifts]
+        tot, run = None, None
+        for j in range(packs):
+            lo = j * span
+            p = jax.lax.dot_general(
+                a[:, lo:lo + span], b[lo:lo + span, :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(jnp.int32)
+            adds = full
+            if last_valid is not None and (j + 1) * pack > last_valid:
+                # K padding past the operand's last group: the macro has no
+                # such groups, so in the last block their fields get no lift
+                # and no comparator fires on their zero counts.
+                live = sum(1 << (fbits * f) for f in range(pack)
+                           if j * pack + f < last_valid)
+                live = jnp.where(last, jnp.int32(live), jnp.int32(ones))
+                adds = [x * live for x in lifts]
+            for x in adds:
+                t = (p + x) & tops
+                run = t if run is None else run + t
+            if (j + 1) % per_sum == 0 or j == packs - 1:
+                part = field_sum(run)
+                tot = part if tot is None else tot + part
+                run = None
         # digital shift-accumulate, weight-plane part 2^q ...
         weight = jax.lax.shift_left(jnp.int32(1), q)
-        acc_ref[...] += weight * jnp.sum(dec, axis=0).astype(jnp.int32)
+        acc_ref[...] += weight * tot
 
-        @pl.when((q == pl.num_programs(2) - 1)
-                 & (kk == pl.num_programs(3) - 1))
+        @pl.when((q == pl.num_programs(2) - 1) & last)
         def _flush():
             # ... and activation-plane part 2^p once per tile: folded row r
             # holds plane p = r // m_pad, found by compares (Mosaic has no
@@ -117,42 +195,59 @@ def _make_kernel(rows: int, m_pad: int, bits_a: int):
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "m_pad", "bits_a",
-                                             "bm", "bn", "bk", "interpret"))
-def bitplane_mac_raw(a_rows, w_planes, thresholds, *, m_pad: int,
+                                             "bm", "bn", "bk", "valid_groups",
+                                             "interpret"))
+def bitplane_mac_raw(a_rows, w_planes, refs, *, m_pad: int,
                      bits_a: int, rows: int = C.ROWS, bm: int = 128,
-                     bn: int = 128, bk: int = 256, interpret: bool = False):
+                     bn: int = 128, bk: int = 256,
+                     valid_groups: int | None = None,
+                     interpret: bool = False):
     """Fused full-pyramid decode MAC over plane-folded activation rows.
 
     a_rows: int8[R, K] in {0,1}: the activation bit-planes [PA, m_pad, K]
     (LSB first) flattened to rows, plane p in rows [p*m_pad, (p+1)*m_pad),
     then zero rows up to R (``bits_a`` = PA); w_planes: int8[PW, K, N] in
-    {0,1}; thresholds: float32[rows] descending.  R, N, K must be divisible
-    by (bm, bn, bk) and bk by rows (ops.py pads).  Returns int32[R, N]: row
-    p*m_pad + m holds sum_q 2^(p+q) * sum_g decoded_count[p, q, g] of row
-    m; summing the PA row blocks gives the product.
+    {0,1}; refs: int32[rows], the comparator references in counts
+    (``kernels.common.count_references`` of the thresholds).  R, N, K must
+    be divisible by (bm, bn, bk) and bk by rows (ops.py pads);
+    ``valid_groups`` is the number of real row-groups, ``ceil(K_orig /
+    rows)`` (defaults to all): the zero groups past it do not decode.
+    Returns int32[R, N]: row p*m_pad + m holds sum_q 2^(p+q) * sum_g
+    decoded_count[p, q, g] of row m; summing the PA row blocks gives the
+    product.
     """
     r, k = a_rows.shape
     pw, k2, n = w_planes.shape
     assert k == k2 and r % bm == 0 and n % bn == 0 and k % bk == 0
     assert bk % rows == 0 and r >= bits_a * m_pad
-    grid = (r // bm, n // bn, pw, k // bk)
-    return pl.pallas_call(
-        _make_kernel(rows, m_pad, bits_a),
-        grid=grid,
+    pack, fbits = group_pack(rows, bk)
+    last_valid = None
+    if valid_groups is not None and valid_groups < k // rows:
+        last_valid = valid_groups - (k // bk - 1) * (bk // rows)
+        assert last_valid > 0, "K padding must lie in the last K-block"
+    field = (np.arange(bk) // rows) % pack
+    scale = np.left_shift(1, fbits * field).astype(np.float32).reshape(1, bk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(r // bm, n // bn, pw, k // bk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, q, kk: (i, kk)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, q, kk: (q, kk, j)),
-            pl.BlockSpec((1, rows), lambda i, j, q, kk: (0, 0)),
+            pl.BlockSpec((bm, bk), lambda i, j, q, kk, c: (i, kk)),
+            pl.BlockSpec((1, bk, bn), lambda i, j, q, kk, c: (q, kk, j)),
+            pl.BlockSpec((1, bk), lambda i, j, q, kk, c: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, q, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.int32),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, q, kk, c: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+    )
+    return pl.pallas_call(
+        _make_kernel(rows, bk, m_pad, bits_a, last_valid),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, n), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
-    )(a_rows.astype(jnp.int8), w_planes.astype(jnp.int8),
-      jnp.asarray(thresholds, jnp.float32).reshape(1, rows))
+    )(jnp.asarray(refs, jnp.int32), a_rows.astype(jnp.int8),
+      w_planes.astype(jnp.int8), jnp.asarray(scale))
 
 
 def _make_noisy_kernel(rows: int, bk: int, bits_w: int, mismatch_sigma,

@@ -13,10 +13,17 @@ axis: M is padded to a multiple of 8, the planes [PA, M', K] become rows
 ``min(bm, PA*M')``, so an 8-row 8-bit decode tick is one 64-row tile and a
 128-row prefill eight 128-row tiles).  The kernel returns one 2^p-weighted
 partial sum per folded row; the wrapper adds the PA row blocks under the
-``fabric.mac`` scope.  Trace-time counters ``fabric.mac.real_rows`` (PA*M)
-and ``fabric.mac.tile_rows`` (folded rows the grid launches) give the row
-fill of each traced shape.  The noisy entry keeps the unfolded [PA, M, K]
-layout, whose grid keys its noise stream.
+``fabric.mac`` scope.  The wrapper also moves the comparator references from
+volts to counts (``kernels.common.count_references``: the voltage map on the
+``rows + 1`` levels, once per call), and the kernel decodes four row-groups
+per dot in packed 6-bit fields (see the kernel module).  Trace-time counters
+``fabric.mac.real_rows`` (PA*M) and ``fabric.mac.tile_rows`` (folded rows
+the grid launches) give the row fill of each traced shape;
+``fabric.mac.groups`` (row-groups the grid decodes) over
+``fabric.mac.group_dots`` (MXU dots issued for them) is the packing factor
+that engaged.  The noisy entry keeps the unfolded [PA, M, K] layout, whose
+grid keys its noise stream, and its voltage-domain decode of fractional
+counts.
 
 The wrappers are deliberately PLAIN functions in front of inner jits: tile
 geometry defaults to the autotuner's cached winner for the call's shape
@@ -39,7 +46,9 @@ from repro.core.decoder import thresholds as core_thresholds
 from repro.core.quant import to_bitplanes
 from repro.kernels import autotune
 from repro.kernels.bitplane_mac.bitplane_mac import (bitplane_mac_noisy_raw,
-                                                     bitplane_mac_raw)
+                                                     bitplane_mac_raw,
+                                                     group_pack)
+from repro.kernels.common import count_references
 from repro.kernels.compat import resolve_interpret
 from repro.telemetry import get_registry
 
@@ -127,10 +136,19 @@ def _bitplane_mac_jit(u_a, u_w, thr, *, bits_a, bits_w, rows, bm, bn, bk,
     reg = get_registry()
     reg.counter("fabric.mac.real_rows").inc(bits_a * m)
     reg.counter("fabric.mac.tile_rows").inc(a_rows.shape[0])
+    # ... and the row-groups the grid decodes over the MXU dots issued for
+    # them: their ratio is the packing factor that engaged.
+    steps = (a_rows.shape[0] // bm) * (w_planes.shape[2] // bn) * bits_w \
+        * (w_planes.shape[1] // bk)
+    groups = steps * (bk // rows)
+    reg.counter("fabric.mac.groups").inc(groups)
+    reg.counter("fabric.mac.group_dots").inc(groups // group_pack(rows, bk)[0])
     with jax.named_scope("fabric.mac"):
-        out = bitplane_mac_raw(a_rows, w_planes, thr, m_pad=m_pad,
-                               bits_a=bits_a, rows=rows, bm=bm, bn=bn,
-                               bk=bk, interpret=interpret)
+        out = bitplane_mac_raw(a_rows, w_planes, count_references(thr, rows),
+                               m_pad=m_pad, bits_a=bits_a, rows=rows, bm=bm,
+                               bn=bn, bk=bk,
+                               valid_groups=-(-u_a.shape[-1] // rows),
+                               interpret=interpret)
         # each activation plane's row block holds its 2^p-weighted partial
         # sums; their sum is the product
         parts = out[:bits_a * m_pad, :n].reshape(bits_a, m_pad, n)[:, :m]

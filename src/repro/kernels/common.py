@@ -1,10 +1,12 @@
 """Shared in-kernel analog-path helpers for the Pallas IMC kernels.
 
-``rbl_decode`` (one bit-plane pair) and ``bitplane_mac`` (the full pyramid)
-evaluate the identical decode stage in-register; keeping it here means a
-threshold tie-break fix or physics recalibration lands in both kernels at
-once.  Pure jnp on values (not refs), so it is safe inside kernel bodies and
-in interpret mode alike.
+``rbl_decode`` (one bit-plane pair) and the noisy ``bitplane_mac`` evaluate
+the identical decode stage in-register, and the noise-free ``bitplane_mac``
+takes its comparator references in counts (:func:`count_references`) from
+the same voltage map and comparison; keeping them here means a threshold
+tie-break fix or physics recalibration lands in every kernel at once.  Pure
+jnp on values (not refs), so it is safe inside kernel bodies and in
+interpret mode alike.
 
 This module also owns the **in-kernel PRNG** the noisy kernels draw from
 (:func:`make_normal_sampler`): on the compiled TPU path it seeds the Mosaic
@@ -57,6 +59,20 @@ def decode_counts(k_float, thr, rows: int):
     for i in range(rows):  # static unroll: rows is small (8)
         dec = dec + (v <= thr[0, i]).astype(jnp.float32)
     return dec
+
+
+def count_references(thresholds, rows: int):
+    """The comparator bank moved from volts to counts: int32[rows] with
+    c_i = #{k in 0..rows : V(k) > thr[i]}.
+
+    V falls with the count, so comparator i (``V <= thr[i]``, as in
+    :func:`decode_counts`) fires on a noise-free count k exactly when
+    k >= c_i.  Evaluated on the ``rows + 1`` levels, once per call; the
+    thresholds stay data, so detuned or re-tuned references move c_i.
+    """
+    levels = counts_to_voltage(jnp.arange(rows + 1, dtype=jnp.float32), rows)
+    thr = jnp.asarray(thresholds, jnp.float32).reshape(rows, 1)
+    return jnp.sum(levels[None, :] > thr, axis=1, dtype=jnp.int32)
 
 
 def decode_counts_noisy(k_float, thr, rows: int, normal, *,

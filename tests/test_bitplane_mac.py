@@ -147,9 +147,12 @@ def test_bitplane_kernel_batch_dims():
 def test_bitplane_kernel_custom_thresholds_detune():
     # Shifting every comparator reference up one level (paper §IV-C corner
     # detuning) must corrupt the decode — proves thresholds are live data.
+    # The activation's high plane is all zeros, so its groups count 0, which
+    # the detuned bank (thr[0] above V(0)) reads as 1; the all-ones plane
+    # counts 8, which it still reads as 8.
     from repro.core.decoder import thresholds as core_thresholds
 
-    ua = jnp.full((8, 16), 3, jnp.int32)
+    ua = jnp.full((8, 16), 1, jnp.int32)
     uw = jnp.full((16, 8), 3, jnp.int32)
     good = core_thresholds(8, mode="physics")
     out_good = bitplane_mac(ua, uw, good, bits_a=2, bits_w=2, interpret=True)
@@ -159,6 +162,8 @@ def test_bitplane_kernel_custom_thresholds_detune():
     out_bad = bitplane_mac(ua, uw, detuned, bits_a=2, bits_w=2,
                            interpret=True)
     assert not np.array_equal(np.asarray(out_bad), np.asarray(out_good))
+    np.testing.assert_array_equal(
+        np.asarray(out_bad), np.asarray(_detuned_oracle(ua, uw, detuned, 2, 2)))
 
 
 # The plane-folded row axis: every M (one row, ragged, a full decode tile,
@@ -184,15 +189,21 @@ FOLD_CASES = [
 ]
 
 
-def _detuned_oracle(ua, uw, thr, bits_a, bits_w, rows=8):
-    """The pyramid with explicit references: batched group counts, the
-    physics voltage, the comparator bank, the 2^(p+q) weights."""
+def _levels(rows):
+    """V(k) for k in 0..rows, the physics voltage of every count."""
     from repro.kernels.common import counts_to_voltage
 
+    return counts_to_voltage(jnp.arange(rows + 1, dtype=jnp.float32), rows)
+
+
+def _detuned_oracle(ua, uw, thr, bits_a, bits_w, rows=8):
+    """The pyramid with explicit references: batched group counts, the
+    physics voltage of each count, the comparator bank, the 2^(p+q)
+    weights."""
     k = ua.shape[-1]
     a = to_bitplanes(ua.reshape(-1, k), bits_a)
     counts = batched_group_counts(a, to_bitplanes(uw, bits_w), rows)
-    v = counts_to_voltage(counts.astype(jnp.float32), rows)
+    v = _levels(rows)[counts]
     dec = sum((v <= thr[i]).astype(jnp.int32) for i in range(rows))
     out = jnp.einsum("i,imgn->mn", plane_pair_weights(bits_a, bits_w), dec)
     return out.reshape(*ua.shape[:-1], uw.shape[-1])
@@ -271,6 +282,134 @@ def test_row_fill_counters(m, rows):
     assert (real.value - r0, tile.value - t0) == (rows, rows)
     bitplane_mac(ua + 0, uw, bits_a=8, bits_w=8, interpret=True)
     assert (real.value - r0, tile.value - t0) == (rows, rows)
+
+
+# The count-domain comparator bank: references c_i tabulated once per call
+# from the thresholds, four group counts packed per dot.  Threshold kinds:
+# the physics defaults; random descending references reaching above V(0)
+# and below V(rows); references set exactly on level voltages.  The oracle
+# and those references take the rows + 1 voltages under jit, as the
+# kernel's wrapper does: XLA folds them at compile time, and its runtime
+# exp (or an op-by-op dispatch) can round a level one ulp apart, which
+# would move a reference set exactly on that level.
+THRESHOLD_KINDS = ["physics", "random", "on_levels"]
+_jit_levels = jax.jit(_levels, static_argnums=0)
+
+
+def _thresholds_of(kind, rows, seed):
+    from repro.core.decoder import thresholds as core_thresholds
+
+    if kind == "physics":
+        return core_thresholds(rows, mode="physics")
+    rng = np.random.default_rng(seed)
+    levels = np.asarray(_jit_levels(rows))
+    if kind == "random":
+        lo, hi = levels[-1] - 0.2, levels[0] + 0.2
+        thr = np.sort(rng.uniform(lo, hi, rows))[::-1].copy()
+        thr[0] = rng.uniform(levels[0], hi)
+        thr[-1] = rng.uniform(lo, levels[-1])
+    else:  # thr[i] is V(i) or V(i + 1): still descending
+        thr = levels[np.arange(rows) + rng.integers(0, 2, rows)]
+    return jnp.asarray(thr, jnp.float32)
+
+
+@pytest.mark.parametrize("rows", [4, 8, 16, 32])
+@pytest.mark.parametrize("kind", THRESHOLD_KINDS)
+def test_count_references_reproduce_voltage_decode(rows, kind):
+    """k >= c_i on every level k is the voltage comparator V(k) <= thr[i];
+    with the physics references it is ``decoder.decode_voltage``."""
+    from repro.core.decoder import decode_voltage
+    from repro.core.rbl import level_voltages
+    from repro.kernels.common import count_references
+
+    thr = _thresholds_of(kind, rows, seed=rows)
+    refs = np.asarray(jax.jit(count_references, static_argnums=1)(thr, rows))
+    k = np.arange(rows + 1)
+    by_count = (k[:, None] >= refs[None, :]).sum(axis=1)
+    v = np.asarray(_jit_levels(rows))
+    by_volts = (v[:, None] <= np.asarray(thr)[None, :]).sum(axis=1)
+    np.testing.assert_array_equal(by_count, by_volts)
+    if kind == "physics":
+        np.testing.assert_array_equal(by_count, np.asarray(decode_voltage(
+            level_voltages(rows, mode="physics"), rows=rows,
+            mode="physics")))
+        np.testing.assert_array_equal(by_count, k)
+
+
+PACK_CASES = [(rows, bits, kind) for rows in (4, 8, 16)
+              for bits in ((2, 2), (4, 8), (8, 8)) for kind in THRESHOLD_KINDS]
+
+
+def _pack_case_check(rows, bits_a, bits_w, kind, bk, m=5, n=12):
+    """bitplane_mac on K = two whole blocks plus five whole groups and a
+    partial one, so the last block holds a pack that is part padding."""
+    from repro.kernels.bitplane_mac.ops import bitplane_mac
+
+    k = 2 * bk + 5 * rows + 3
+    rng = np.random.default_rng([rows, bits_a, bits_w, bk])
+    ua = jnp.asarray(rng.integers(0, 1 << bits_a, size=(m, k)), jnp.int32)
+    uw = jnp.asarray(rng.integers(0, 1 << bits_w, size=(k, n)), jnp.int32)
+    thr = _thresholds_of(kind, rows, seed=bits_a * 16 + bits_w)
+    out = np.asarray(bitplane_mac(ua, uw, thr, bits_a=bits_a, bits_w=bits_w,
+                                  rows=rows, bk=bk, interpret=True))
+    oracle = jax.jit(_detuned_oracle, static_argnums=(3, 4, 5))
+    np.testing.assert_array_equal(
+        out, np.asarray(oracle(ua, uw, thr, bits_a, bits_w, rows)))
+    if kind == "physics":
+        np.testing.assert_array_equal(out, np.asarray(
+            bitplane_mac_batched_ref(ua, uw, bits_a=bits_a, bits_w=bits_w,
+                                     rows=rows)))
+        np.testing.assert_array_equal(out, np.asarray(ua) @ np.asarray(uw))
+
+
+@pytest.mark.parametrize("rows,bits,kind", PACK_CASES)
+def test_packed_count_bank_bitexact_vs_oracles(rows, bits, kind):
+    """Four groups per dot (``rows <= 31``, whole packs per block) decode
+    exactly as the voltage-domain oracles, thresholds detuned or not."""
+    from repro.kernels.bitplane_mac.bitplane_mac import group_pack
+
+    assert group_pack(rows, 8 * rows)[0] == 4
+    _pack_case_check(rows, *bits, kind, bk=8 * rows)
+
+
+@pytest.mark.parametrize("rows,bk,kind", [
+    (32, 128, "physics"), (32, 128, "random"), (32, 128, "on_levels"),
+    (8, 40, "random")])
+def test_one_group_per_dot_bitexact_vs_oracles(rows, bk, kind):
+    """Counts past 31 (``rows`` 32), or a block of five groups, take one
+    group per dot with the same count-domain compare, and stay exact."""
+    from repro.kernels.bitplane_mac.bitplane_mac import group_pack
+
+    assert group_pack(rows, bk)[0] == 1
+    _pack_case_check(rows, 4, 8, kind, bk=bk)
+
+
+@pytest.mark.parametrize("m,rows,per_dot", [(8, 8, 4), (128, 8, 4),
+                                            (8, 32, 1), (128, 32, 1)])
+def test_group_pack_counters(m, rows, per_dot):
+    """``fabric.mac.groups``/``.group_dots`` count the row-groups decoded
+    and the MXU dots issued for them, once per traced shape: four groups
+    per dot at ``rows`` 8, one at ``rows`` 32, in a decode shape (one
+    64-row tile) and a prefill shape (eight 128-row tiles)."""
+    from repro.kernels.bitplane_mac.ops import _bitplane_mac_jit
+    from repro.telemetry import get_registry
+
+    reg = get_registry()
+    groups, dots = (reg.counter("fabric.mac.groups"),
+                    reg.counter("fabric.mac.group_dots"))
+    ua, uw = _mk_unsigned(8, m, 64, 8, seed=m + rows)
+    _bitplane_mac_jit.clear_cache()  # this shape is traced here first
+    g0, d0 = groups.value, dots.value
+    out = bitplane_mac(ua, uw, bits_a=8, bits_w=8, rows=rows, bm=128,
+                       bn=128, bk=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ua) @ np.asarray(uw))
+    steps = (8 * m // min(128, 8 * m)) * 8  # row tiles x weight planes
+    assert groups.value - g0 == steps * 128 // rows
+    assert (groups.value - g0) == per_dot * (dots.value - d0)
+    bitplane_mac(ua + 0, uw, bits_a=8, bits_w=8, rows=rows, bm=128, bn=128,
+                 bk=128, interpret=True)
+    assert groups.value - g0 == steps * 128 // rows
 
 
 # ------------------------------------------------------- fabric wiring
